@@ -46,8 +46,9 @@ def _claim_kernel(state_ref, cycle_ref, new_state_ref, ids_ref, *, k: int, n: in
     cycle = cycle_ref[...].reshape(1, n)
     key = jnp.where(state == AVAILABLE, cycle, _INT_MAX)
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
     new_state = state
-    ids = jnp.zeros((k,), jnp.int32)
+    ids = jnp.zeros((1, k), jnp.int32)
     for i in range(k):  # k is small & static: unrolled argmin cascade
         m = jnp.min(key)
         # lowest index among minima (deterministic tie-break)
@@ -56,7 +57,8 @@ def _claim_kernel(state_ref, cycle_ref, new_state_ref, ids_ref, *, k: int, n: in
         take = found & (iota == idx)
         new_state = jnp.where(take, CLAIMED, new_state)
         key = jnp.where(take, _INT_MAX, key)
-        ids = ids.at[i].set(jnp.where(found, idx, n).astype(jnp.int32))
+        # lane i by a select on a lane iota (Mosaic lowers no scatter)
+        ids = jnp.where(lane == i, jnp.where(found, idx, n), ids)
     new_state_ref[...] = new_state.reshape(n)
     ids_ref[...] = ids
 
@@ -66,23 +68,25 @@ def _claim_block_kernel(state_ref, cycle_ref, cand_cycle_ref, cand_id_ref,
     """Tiled path, per-grid-block body: local k-way min over this tile,
     emitting the k best (cycle, global id) candidates for the merge."""
     b = pl.program_id(0)
-    state = state_ref[...].reshape(1, block_n)
-    cycle = cycle_ref[...].reshape(1, block_n)
+    state = state_ref[...]
+    cycle = cycle_ref[...]
     gids = jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1) + b * block_n
     # Padding lanes (gids >= n) were materialized as CLAIMED by the wrapper,
     # but mask them here too so the kernel is safe for any input.
     key = jnp.where((state == AVAILABLE) & (gids < n), cycle, _INT_MAX)
-    cand_c, cand_i = [], []
-    for _ in range(k):
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    cand_c = jnp.full((1, k), _INT_MAX, jnp.int32)
+    cand_i = jnp.full((1, k), n, jnp.int32)
+    for i in range(k):
         m = jnp.min(key)
         idx = jnp.min(jnp.where(key == m, gids, _INT_MAX))
         found = m != _INT_MAX
         take = found & (gids == idx)
         key = jnp.where(take, _INT_MAX, key)
-        cand_c.append(jnp.where(found, m, _INT_MAX))
-        cand_i.append(jnp.where(found, idx, n).astype(jnp.int32))
-    cand_cycle_ref[...] = jnp.stack(cand_c).reshape(1, k)
-    cand_id_ref[...] = jnp.stack(cand_i).reshape(1, k)
+        cand_c = jnp.where(lane == i, jnp.where(found, m, _INT_MAX), cand_c)
+        cand_i = jnp.where(lane == i, jnp.where(found, idx, n), cand_i)
+    cand_cycle_ref[...] = cand_c
+    cand_id_ref[...] = cand_i
 
 
 def _cmp_claim_tiled(state, cycle, *, k: int, block_n: int, interpret: bool):
@@ -92,23 +96,27 @@ def _cmp_claim_tiled(state, cycle, *, k: int, block_n: int, interpret: bool):
     state_p = jnp.pad(state, (0, pad), constant_values=CLAIMED) if pad else state
     cycle_p = jnp.pad(cycle, (0, pad)) if pad else cycle
     kernel = functools.partial(_claim_block_kernel, k=k, block_n=block_n, n=n)
+    # Tiles are [nb, 1, block_n] with the grid dim squeezed: each block's
+    # last two dims (1, block_n) span the whole array, which the TPU block
+    # layout accepts for any block_n (a (1, block_n) block of an
+    # [nb, block_n] array would not be sublane-aligned).
     cand_c, cand_i = pl.pallas_call(
         kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_n), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, block_n), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, block_n), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, k), jnp.int32),
-            jax.ShapeDtypeStruct((nb, k), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, k), jnp.int32),
         ],
         interpret=interpret,
-    )(state_p.reshape(nb, block_n), cycle_p.reshape(nb, block_n))
+    )(state_p.reshape(nb, 1, block_n), cycle_p.reshape(nb, 1, block_n))
     # Cross-block merge: global order is lexicographic (cycle, id) ascending —
     # identical to the fused kernel's cascade and lax.top_k's tie-breaking.
     flat_c = cand_c.reshape(-1)
@@ -146,8 +154,8 @@ def cmp_claim(state: jax.Array, cycle: jax.Array, *, k: int,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((k,), jnp.int32),
+            jax.ShapeDtypeStruct((1, k), jnp.int32),
         ],
         interpret=interpret,
     )(state, cycle)
-    return new_state, ids
+    return new_state, ids.reshape(k)

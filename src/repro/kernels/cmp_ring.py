@@ -69,9 +69,11 @@ def _ring_kernel(state_ref, cycle_ref, meta_ref, req_ref,
     cycle = jnp.where(take, enq + 1 + off, cycle)
 
     # Stage C: k-way earliest-claim cascade (Alg 3 Phases 1-3), masked to
-    # the first `want` lanes. k is small & static: unrolled.
+    # the first `want` lanes. k is small & static: unrolled. Claim lane i is
+    # written by a select on a lane iota (Mosaic lowers no scatter).
     key = jnp.where(state == AVAILABLE, cycle, _INT_MAX)
-    claimed = jnp.full((k,), -1, jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    claimed = jnp.full((1, k), -1, jnp.int32)
     max_claimed = dc
     for i in range(k):
         m = jnp.min(key)
@@ -80,7 +82,7 @@ def _ring_kernel(state_ref, cycle_ref, meta_ref, req_ref,
         tk = found & (iota == idx)
         state = jnp.where(tk, CLAIMED, state)
         key = jnp.where(tk, _INT_MAX, key)
-        claimed = claimed.at[i].set(jnp.where(found, m, -1))
+        claimed = jnp.where(lane == i, jnp.where(found, m, -1), claimed)
         max_claimed = jnp.where(found, jnp.maximum(max_claimed, m), max_claimed)
 
     # Stage P: monotone frontier publish (Alg 3 Phase 5).
@@ -108,7 +110,7 @@ def cmp_ring_step(state: jax.Array, cycle: jax.Array, meta: jax.Array,
     n = state.shape[0]
     req = jnp.stack([jnp.minimum(req[0], n), req[1]]).astype(jnp.int32)
     kernel = functools.partial(_ring_kernel, k=k, n=n, window=window)
-    return pl.pallas_call(
+    new_state, new_cycle, new_meta, claimed = pl.pallas_call(
         kernel,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -126,7 +128,8 @@ def cmp_ring_step(state: jax.Array, cycle: jax.Array, meta: jax.Array,
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((2,), jnp.int32),
-            jax.ShapeDtypeStruct((k,), jnp.int32),
+            jax.ShapeDtypeStruct((1, k), jnp.int32),
         ],
         interpret=interpret,
     )(state, cycle, meta, req)
+    return new_state, new_cycle, new_meta, claimed.reshape(k)

@@ -1,6 +1,6 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode — the kernel body
+Off a TPU, kernels run in interpret mode — the kernel body
 executes in Python for correctness validation; on TPU the same calls compile
 to Mosaic. Model code calls these; layouts are adapted here.
 """
@@ -14,8 +14,10 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import paged_attention as _pa
 
 
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
+def on_tpu() -> bool:
+    """True when JAX's default device is a TPU: kernels compile to Mosaic.
+    Elsewhere they run in interpret mode (or as their jnp oracle)."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, sliding_window=0,
@@ -29,26 +31,24 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=0,
     bk = min(block_k, bq)
     out = _fa.flash_attention(qt, kt, vt, causal=causal,
                               sliding_window=sliding_window,
-                              block_q=bq, block_k=bk, interpret=_interpret())
+                              block_q=bq, block_k=bk, interpret=not on_tpu())
     return out.transpose(0, 2, 1, 3)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens):
     """q [B, H, hd]; pages [P, KV, page, hd] -> [B, H, hd]."""
     return _pa.paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                               interpret=_interpret())
+                               interpret=not on_tpu())
 
 
 _ref_ring_jit = None
 
 
-def ring_step(state, cycle, meta, req, *, k, window, use_pallas=None):
+def ring_step(state, cycle, meta, req, *, k, window, use_pallas: bool):
     """Fused admission-ring step (reclaim + enqueue-many + k-way claim +
-    frontier publish) in ONE device invocation. On TPU this is the Pallas
-    kernel; elsewhere the jit'd pure-jnp oracle runs as the fast path
-    (interpret-mode Pallas is reserved for the equivalence tests)."""
-    if use_pallas is None:
-        use_pallas = not _interpret()
+    frontier publish) in ONE device invocation: the Pallas kernel when
+    ``use_pallas`` (the ring picks it on a TPU), else the jit'd pure-jnp
+    oracle (interpret-mode Pallas is reserved for the equivalence tests)."""
     if use_pallas:
         from repro.kernels import cmp_ring as _ring
 
@@ -67,4 +67,4 @@ def claim(state, cycle, *, k, block_n=None):
     Pools larger than one VMEM block dispatch to the tiled grid kernel
     (block-local k-way min + cross-block merge)."""
     return _claim.cmp_claim(state, cycle, k=k, block_n=block_n,
-                            interpret=_interpret())
+                            interpret=not on_tpu())

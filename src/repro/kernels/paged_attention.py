@@ -13,7 +13,9 @@ needs from HBM into VMEM. The last grid axis (pages) iterates sequentially,
 carrying the online-softmax state in VMEM scratch.
 
 Layouts: q [B, H, hd] (one decode token); k/v pages [P, KV, page, hd];
-block_tables [B, pages_per_seq] int32; seq_lens [B] int32.
+block_tables [B, pages_per_seq] int32; seq_lens [B] int32. Inside the call q
+and the output are viewed as [B, H, 1, hd], so each (1, hd) block spans the
+last two dims whole, as the TPU block layout requires.
 """
 
 from __future__ import annotations
@@ -44,9 +46,9 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     @pl.when(p * page < seq_len)
     def _compute():
-        q = q_ref[0, 0].reshape(1, -1).astype(jnp.float32)       # [1, hd]
-        k = k_ref[0, 0].astype(jnp.float32)                      # [page, hd]
-        v = v_ref[0, 0].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                       # [1, hd]
+        k = k_ref[...].astype(jnp.float32)                       # [page, hd]
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale  # [1, page]
         pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         mask = pos < seq_len
@@ -61,8 +63,8 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     @pl.when(p == np_ - 1)
     def _out():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).reshape(
-            o_ref.shape[2:]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -78,7 +80,6 @@ def paged_attention(
     B, H, hd = q.shape
     P, KV, page, _ = k_pages.shape
     pps = block_tables.shape[1]
-    rep = H // KV
     sm_scale = 1.0 / (hd ** 0.5)
 
     kernel = functools.partial(_paged_kernel, page=page, sm_scale=sm_scale)
@@ -86,22 +87,25 @@ def paged_attention(
         num_scalar_prefetch=2,
         grid=(B, H, pps),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, bt, sl: (b, h, 0)),
-            pl.BlockSpec((1, 1, page, hd),
+            pl.BlockSpec((None, None, 1, hd),
+                         lambda b, h, p, bt, sl: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, page, hd),
                          lambda b, h, p, bt, sl: (bt[b, p], h % KV, 0, 0)),
-            pl.BlockSpec((1, 1, page, hd),
+            pl.BlockSpec((None, None, page, hd),
                          lambda b, h, p, bt, sl: (bt[b, p], h % KV, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, p, bt, sl: (b, h, 0)),
+        out_specs=pl.BlockSpec((None, None, 1, hd),
+                               lambda b, h, p, bt, sl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, hd), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
         interpret=interpret,
-    )(block_tables, seq_lens, q, k_pages, v_pages)
+    )(block_tables, seq_lens, q.reshape(B, H, 1, hd), k_pages, v_pages)
+    return out.reshape(B, H, hd)

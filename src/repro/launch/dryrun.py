@@ -203,7 +203,6 @@ def _measure_cfg(cfg: ModelConfig, shape: InputShape, mesh) -> Dict[str, Any]:
         lowered = lower_cell(vcfg, shape, mesh)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
-        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
         wire = R.collective_wire_bytes(compiled.as_text())
         meas[name] = {
             "flops": float(cost.get("flops", 0.0)),

@@ -368,6 +368,9 @@ def main() -> None:
         print(json.dumps(config.to_json(), indent=2, sort_keys=True))
         return
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.verify_single_host:
         verify_single_host(args, config)
         return

@@ -9,6 +9,7 @@ embeddings) are prepended to the token embeddings.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -22,7 +23,9 @@ from repro.models import layers as L
 def _shard_batch(x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Re-anchor the batch sharding after the embedding gather (whose output
     sharding is ambiguous under 2-D sharded embeddings — see ModelConfig
-    .batch_axes). No-op when no mesh/batch_axes configured."""
+    .batch_axes). A GSPMD constraint: the mesh must have ``Auto`` axes, as
+    every mesh from :mod:`repro.launch.mesh` does. No-op when no
+    mesh/batch_axes configured."""
     if cfg.batch_axes and x.shape[0] % 2 == 0:
         from jax.sharding import PartitionSpec as P
         spec = P(tuple(cfg.batch_axes), *([None] * (x.ndim - 1)))
@@ -38,7 +41,11 @@ def _shard_batch(x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
+    """Random params for ``cfg`` from ``key``. Jitted, so each weight's
+    float32 draw is fused into its cast: no whole float32 layer stack is
+    ever materialised (at yi-6b widths one would be 5.4 GiB)."""
     dt = jnp.dtype(cfg.dtype)
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     params: Dict[str, Any] = {

@@ -100,7 +100,7 @@ def sample_fabric_gauges(replica_set, engines=(), hub=None) -> dict:
     }
     rings = {}
     for eng in engines:
-        ring = getattr(eng, "_dev_admit", None)
+        ring = getattr(eng, "admission_ring", None)
         if ring is not None:
             rings[eng.sched.rid] = sample_admission_ring(ring)
     if rings:

@@ -85,7 +85,7 @@ class MetricsHub:
         for eng in engines:
             rec = self.recorder(eng.sched.rid, eng.sched.addr.host)
             eng._obs = rec
-            ring = getattr(eng, "_dev_admit", None)
+            ring = getattr(eng, "admission_ring", None)
             if ring is not None:
                 ring._obs = rec
 

@@ -19,14 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax >= 0.6 exposes shard_map at the top level (replication check renamed to
-# check_vma); 0.4.x keeps it in jax.experimental with check_rep.
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - exercised on jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 # ---------------------------------------------------------------------------
 # int8 error-feedback compression
@@ -64,10 +56,10 @@ def cross_pod_grad_reduce(grads: Any, err: Any, mesh: Mesh) -> Tuple[Any, Any]:
         return grads, err
 
     def one(g, e):
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda gg, ee: compressed_psum(gg, ee, "pod"),
             mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         return fn(g, e)
 

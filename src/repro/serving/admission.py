@@ -31,7 +31,6 @@ keeps the pure host path.
 
 from __future__ import annotations
 
-from collections import deque
 from typing import Any, List, Tuple
 
 import jax
@@ -43,7 +42,7 @@ from repro.kernels import ops as kernel_ops
 def resolve_device_admission(flag) -> bool:
     """Map a config flag (False | True | "auto") to an enable decision."""
     if flag == "auto":
-        return jax.devices()[0].platform == "tpu"
+        return kernel_ops.on_tpu()
     return bool(flag)
 
 
@@ -63,7 +62,8 @@ class DeviceAdmissionRing:
       window: protection window W for ring-slot recycling (paper Alg 4);
         defaults to capacity // 4.
       use_pallas: force the Pallas kernel (True) or the jit'd oracle (False);
-        None picks by platform (Pallas on TPU).
+        None picks by platform (Pallas on TPU). Resolved once, here, so
+        ``use_pallas`` always says which path the ring runs.
     """
 
     def __init__(self, *, k: int, claim_block: int = 0, capacity: int = 0,
@@ -74,7 +74,8 @@ class DeviceAdmissionRing:
         self.capacity = int(capacity) if capacity else max(
             64, 2 * self.claim_block)
         self.window = int(window) if window else self.capacity // 4
-        self.use_pallas = use_pallas
+        self.use_pallas = (kernel_ops.on_tpu() if use_pallas is None
+                           else bool(use_pallas))
         self.state = np.zeros((self.capacity,), np.int32)
         self.cycle = np.zeros((self.capacity,), np.int32)
         self.meta = np.zeros((2,), np.int32)  # [enq_cycle, deque_cycle]
@@ -89,6 +90,14 @@ class DeviceAdmissionRing:
         self._served = 0  # consumed front of _claimed
         self.stats = {"steps": 0, "kernel_calls": 0, "pushed": 0,
                       "claimed": 0, "rejected": 0}
+
+    @classmethod
+    def for_engine(cls, max_batch: int) -> "DeviceAdmissionRing":
+        """The ring of an engine with ``max_batch`` lanes: claim look-ahead
+        well past max_batch, since the fused invocation's fixed dispatch
+        cost divides by claim_block, and the ordering relaxation it buys
+        stays bounded by the prefetch depth."""
+        return cls(k=max_batch, claim_block=8 * max_batch)
 
     # flight-recorder attachment (repro.obs): kernel calls and flushes are
     # already amortized/rare, so both are recorded unconditionally when a

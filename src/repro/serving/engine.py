@@ -33,7 +33,6 @@ import dataclasses
 import itertools
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,7 +40,7 @@ from repro.configs.base import ModelConfig
 from repro.sched import Envelope, QueueClass, ReplicaSet, Scheduler
 from repro.serving.admission import DeviceAdmissionRing, resolve_device_admission
 from repro.serving.kv_cache import PagedKVPool
-from repro.serving.paged_model import paged_forward
+from repro.serving.paged_model import make_paged_forward
 
 
 @dataclasses.dataclass
@@ -119,8 +118,7 @@ class Engine:
         # Prefill and decode are the same function traced at different
         # sequence lengths — one jit, one compilation cache. Replicas pass a
         # shared callable so N engines share one compilation cache.
-        self._forward = forward_fn or jax.jit(
-            lambda p, t, kp, vp, bt, sl: paged_forward(p, t, cfg, kp, vp, bt, sl))
+        self._forward = forward_fn or make_paged_forward(cfg)
         # Device-resident admission (DESIGN.md §12): policy-drained batches
         # route through a bounded CMP ring on the accelerator — one fused
         # reclaim+enqueue+claim+publish invocation per step. "auto" enables
@@ -129,11 +127,7 @@ class Engine:
         self._dev_admit = None
         self._admit_prefetch = 0
         if resolve_device_admission(device_admission):
-            # claim look-ahead well past max_batch: the fused invocation's
-            # fixed dispatch cost divides by claim_block, and the ordering
-            # relaxation it buys stays bounded by the prefetch depth.
-            self._dev_admit = DeviceAdmissionRing(
-                k=max_batch, claim_block=max(8 * max_batch, 2 * max_batch))
+            self._dev_admit = DeviceAdmissionRing.for_engine(max_batch)
             self._admit_prefetch = (int(admit_prefetch)
                                     or 2 * self._dev_admit.claim_block)
 
@@ -143,6 +137,11 @@ class Engine:
         prefetch), derived from the scheduler's and ring's own counters —
         no engine-side bookkeeping to drift."""
         return self.sched.pending() + self.ring_pending
+
+    @property
+    def admission_ring(self) -> Optional[DeviceAdmissionRing]:
+        """The device admission ring (None on the host path)."""
+        return self._dev_admit
 
     @property
     def ring_pending(self) -> int:
@@ -495,8 +494,7 @@ class EngineReplicaGroup:
         self.replica_set = replica_set
         self.sched = replica_set.scheduler
         self.num_replicas = replica_set.num_replicas
-        self._fwd = forward_fn or jax.jit(
-            lambda p, t, kp, vp, bt, sl: paged_forward(p, t, cfg, kp, vp, bt, sl))
+        self._fwd = forward_fn or make_paged_forward(cfg)
         # the fabric-wide budgets + geometry, retained so resize() can
         # re-partition them across a different replica count
         self.cfg, self.params = cfg, params

@@ -116,3 +116,11 @@ def paged_forward(params, tokens, cfg: ModelConfig, k_pages, v_pages,
     x = L.norm(x, params["final_norm"], cfg.norm)
     logits = M._logits(x[:, -1:], params, cfg)[:, 0]
     return logits, new_kp.reshape(k_pages.shape), new_vp.reshape(v_pages.shape)
+
+
+def make_paged_forward(cfg: ModelConfig):
+    """The engine's compiled step: ``(params, tokens, k_pages, v_pages,
+    block_tables, seq_lens) -> (logits, k_pages', v_pages')``. Prefill and
+    decode are this one jit traced at different sequence lengths."""
+    return jax.jit(lambda p, t, kp, vp, bt, sl:
+                   paged_forward(p, t, cfg, kp, vp, bt, sl))

@@ -1,0 +1,130 @@
+"""Compile the main path's kernels and the full-width decode step for a
+described TPU v5e chip, with no chip attached.
+
+The TPU compiler refuses what interpret mode accepts: an in-kernel scatter,
+a block not aligned to the tiling, a program larger than the chip's memory.
+Each test lowers one program for one chip of a described ``v5e:2x2``
+topology at the sizes the serving path uses. The topology is described
+inside the ``topo`` fixture, never at import: only one process at a time
+may load the TPU library, and under several test workers only the worker
+that runs this file may try.
+"""
+
+import importlib.util
+import os
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import cmp_claim, cmp_ring, flash_attention, paged_attention
+from repro.models import init_params
+from repro.serving.admission import DeviceAdmissionRing
+from repro.serving.paged_model import make_paged_forward
+
+GiB = 2 ** 30
+# v5e has 16 GiB of HBM; the smoke geometry must leave at least 1 GiB of it
+# for what the process holds besides one forward call.
+FITS_BYTES = 15 * GiB
+
+
+def _chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("max_batch", [4, 8])
+def test_ring_step_compiles_at_engine_sizes(one_chip, max_batch):
+    ring = DeviceAdmissionRing.for_engine(max_batch)
+    n = ring.capacity
+    fn = jax.jit(lambda s, c, m, r: cmp_ring.cmp_ring_step(
+        s, c, m, r, k=ring.claim_block, window=ring.window))
+    compiled = fn.lower(_spec(one_chip, (n,)), _spec(one_chip, (n,)),
+                        _spec(one_chip, (2,)), _spec(one_chip, (2,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n", [1024, 4096])  # one VMEM block / tiled grid
+def test_claim_compiles(one_chip, n):
+    fn = jax.jit(lambda s, c: cmp_claim.cmp_claim(s, c, k=8))
+    compiled = fn.lower(_spec(one_chip, (n,)), _spec(one_chip, (n,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_compiles_at_yi6b_widths(one_chip):
+    cfg = get_config("yi-6b")
+    sm = _chip_smoke()
+    hd, bf = cfg.resolved_head_dim, jnp.bfloat16
+    pages = (sm.NUM_PAGES, cfg.num_kv_heads, sm.PAGE_SIZE, hd)
+    fn = jax.jit(paged_attention.paged_attention)
+    compiled = fn.lower(
+        _spec(one_chip, (sm.MAX_BATCH, cfg.num_heads, hd), bf),
+        _spec(one_chip, pages, bf), _spec(one_chip, pages, bf),
+        _spec(one_chip, (sm.MAX_BATCH, sm.MAX_SEQ // sm.PAGE_SIZE)),
+        _spec(one_chip, (sm.MAX_BATCH,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles_at_yi6b_widths(one_chip):
+    cfg = get_config("yi-6b")
+    hd, S, bf = cfg.resolved_head_dim, 2048, jnp.bfloat16
+    fn = jax.jit(lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128))
+    compiled = fn.lower(
+        _spec(one_chip, (1, cfg.num_heads, S, hd), bf),
+        _spec(one_chip, (1, cfg.num_kv_heads, S, hd), bf),
+        _spec(one_chip, (1, cfg.num_kv_heads, S, hd), bf)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_yi6b_decode_step_fits_one_chip(one_chip):
+    """The engine's decode step at full width, with the pool chip_smoke.py
+    serves from: params + pool + the call's outputs and temporaries fit."""
+    cfg = get_config("yi-6b")
+    sm = _chip_smoke()
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    params = jax.tree_util.tree_map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), params)
+    pool = _spec(one_chip, (cfg.num_layers, sm.NUM_PAGES, cfg.num_kv_heads,
+                            sm.PAGE_SIZE, cfg.resolved_head_dim),
+                 jnp.dtype(cfg.dtype))
+    B = sm.MAX_BATCH
+    compiled = make_paged_forward(cfg).lower(
+        params, _spec(one_chip, (B, 1)), pool, pool,
+        _spec(one_chip, (B, sm.MAX_SEQ // sm.PAGE_SIZE)),
+        _spec(one_chip, (B,))).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"decode step: args {mem.argument_size_in_bytes / GiB:.2f} GiB, "
+          f"out {mem.output_size_in_bytes / GiB:.2f} GiB, temp "
+          f"{mem.temp_size_in_bytes / GiB:.2f} GiB, alias "
+          f"{mem.alias_size_in_bytes / GiB:.2f} GiB")
+    assert total <= FITS_BYTES, total / GiB

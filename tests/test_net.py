@@ -4,7 +4,10 @@ worker processes, prefetch credit, batched reseat frames, chaos
 telemetry export path."""
 
 import json
+import os
 import random
+import subprocess
+import sys
 import time
 
 import pytest
@@ -311,3 +314,16 @@ def test_rtt_percentiles_export_to_stats_and_prometheus():
         if line and not line.startswith("#"):
             assert line.startswith("repro_")
             float(line.rsplit(" ", 1)[1])
+
+
+def test_wire_worker_imports_no_jax():
+    """Wire-transport host workers run ``repro.net.server`` in their own
+    processes; importing it must not load JAX, or a worker could try to
+    take the accelerator that the serving process holds."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import repro.net.server; import sys; assert not any("
+            "m == 'jax' or m.startswith('jax.') for m in sys.modules)")
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, env=env)
+    assert r.returncode == 0, r.stdout + r.stderr

@@ -68,9 +68,7 @@ _CALIB = textwrap.dedent("""
     c = {}
     for u in (1, 2):
         comp = jax.jit(functools.partial(g, unroll=u)).lower(xs, ws).compile()
-        ca = comp.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        c[u] = ca["flops"]
+        c[u] = comp.cost_analysis()["flops"]
     slope = c[2] - c[1]
     total = c[1] - slope + R * slope
     exact = 6 * M**3 * R  # fwd 2M^3 + bwd 4M^3 per layer

@@ -8,7 +8,6 @@ import textwrap
 import jax
 
 from repro.configs import get_config
-from repro.launch.mesh import make_debug_mesh  # noqa: F401 (import sanity)
 from repro.parallel.sharding import param_spec, param_specs
 from repro.models import init_params
 
@@ -44,12 +43,13 @@ _SMALL_MESH = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     import dataclasses
     from repro.configs import get_config
+    from repro.launch.mesh import make_debug_mesh
     from repro.models import model as M
     from repro.models import init_params
     from repro.parallel import sharding as S
     from repro.training import optimizer as O
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_debug_mesh(4, 2)
     cfg = dataclasses.replace(get_config("yi_6b", smoke=True),
                               batch_axes=("data",))
     opt_cfg = O.OptConfig(lr=1e-3, warmup_steps=1, total_steps=10)
