@@ -1,0 +1,117 @@
+"""``BENCHMARK.json`` keeps the benchmark's contract and names only files
+that exist."""
+
+import importlib
+import json
+import re
+from pathlib import Path
+
+import pytest
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+LINE = re.compile(r"^[^\t\n]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def metrics():
+    return BENCH["end_to_end"] + BENCH["per_layer"]
+
+
+def reported(metric, cell):
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def test_top_level_keys_command_and_paths():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads",
+                          "end_to_end", "per_layer"}
+    assert BENCH["command"] == ["python3", "bench/run.py"]
+    assert BENCH["paths"] == ["bench"]
+    assert isinstance(BENCH["run_seconds"], int) and 1 <= BENCH["run_seconds"] <= 51
+    assert len(json.dumps(BENCH)) < 64 * 1024
+
+
+def test_a_full_check_with_24_cells_fits_its_time():
+    cells = 24
+    runs = 2 + 14 * cells
+    assert runs * (BENCH["run_seconds"] + 60) + cells * 2 * 90 + 1200 <= 43200
+
+
+def test_names_units_and_entry_keys():
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and LINE.match(c["source"]) and LINE.match(c["why"])
+        assert all(NAME.match(k) for k in c["reduced"]) and len(c["reduced"]) <= 16
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
+        assert w["chips"] in (1, 4) and LINE.match(w["why"])
+    for m in BENCH["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in BENCH["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+        assert m["source"] in SOURCES and LINE.match(m["layer"])
+    for m in metrics():
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+    names = [x["name"] for group in ("configs", "workloads") for x in BENCH[group]]
+    names += [m["name"] for m in metrics()]
+    assert len(names) == len(set(names))
+    assert len({(w["config"], w["traffic"]) for w in BENCH["workloads"]}) == len(BENCH["workloads"])
+
+
+def test_setup_and_roofline_metrics():
+    setup = [m for m in BENCH["end_to_end"] if m["name"] == "setup_s"]
+    assert setup and setup[0]["bound"] <= 0.25 and "workloads" not in setup[0]
+    for m in BENCH["per_layer"]:
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+    assert any("mfu" in m["name"] for m in BENCH["per_layer"])
+
+
+def test_every_config_has_a_cell_and_its_files():
+    used = {w["config"] for w in BENCH["workloads"]}
+    for c in BENCH["configs"]:
+        assert c["name"] in used
+        assert c["file"].startswith("bench/")
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
+        importlib.import_module(f"bench.configs.{cfg['reference']}")
+    for w in BENCH["workloads"]:
+        assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").is_file()
+        limits = json.loads((ROOT / "bench" / "limits" / f"{w['name']}.json").read_text())
+        assert limits["max_logit_gap"] > 0
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_each_cell_reports_setup_another_end_to_end_and_a_layer(cell):
+    e2e = [m["name"] for m in BENCH["end_to_end"] if reported(m, cell)]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert any(reported(m, cell) for m in BENCH["per_layer"])
+
+
+def test_each_layer_metric_moves_a_metric_its_cells_report():
+    cells = {w["name"] for w in BENCH["workloads"]}
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        for cell in (m.get("workloads") or cells):
+            assert cell in cells and reported(e2e[m["moves"]], cell)
+        for w in m.get("workloads", []):
+            assert w in cells
+
+
+def test_every_metric_has_a_reader():
+    for m in metrics():
+        if m["name"] != "setup_s":
+            assert callable(importlib.import_module(f"bench.metrics.{m['name']}").read)
+
+
+def test_layers_are_named_alike():
+    layers = {}
+    for m in BENCH["per_layer"]:
+        layers.setdefault(m["layer"].split(" (")[0], set()).add(m["layer"])
+    assert all(len(v) == 1 for v in layers.values())
