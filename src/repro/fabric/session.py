@@ -34,6 +34,7 @@ from repro.control import ControlHandle
 from repro.fabric.config import FabricConfig, FabricConfigError
 from repro.fabric.stats import (SloView, StatsView, _json_safe,
                                 class_view_from_snapshot)
+from repro.obs.recorder import NO_SPAN, span
 from repro.sched import QueueClass, ReplicaSet, Scheduler, make_transport
 from repro.sched.tenants import (TIERS, TenantMap, TenantQuotaLedger,
                                  TenantRouter, TenantStatsTable,
@@ -294,6 +295,8 @@ class Fabric:
             tclose = getattr(self._replica_set.transport, "close", None)
             if callable(tclose):
                 tclose()
+            if self._obs_hub is not None:
+                self._obs_hub.close()
 
     def __enter__(self) -> "Fabric":
         return self
@@ -450,8 +453,14 @@ class Fabric:
         """One fabric iteration: every replica admits/decodes (serving) or
         drains one batch (scheduler-only), starved replicas steal, and the
         checkpoint cadence fires when due. Returns completed requests
-        (serving) or ``(view, envelope)`` deliveries (scheduler-only)."""
+        (serving) or ``(view, envelope)`` deliveries (scheduler-only).
+        With an obs hub attached the step is one ``fabric.step`` span."""
         self._check_open()
+        hub = self._obs_hub
+        with NO_SPAN if hub is None else span("fabric.step"):
+            return self._step()
+
+    def _step(self) -> List:
         self.step_count += 1
         if self._group is not None:
             out = self._group.step()
@@ -481,13 +490,8 @@ class Fabric:
         hub = self._obs_hub
         if (hub is not None and
                 self.step_count % hub.config.sample_every_n_steps == 0):
-            hub.sample(self._replica_set, self.engines)
-            if hub.config.snapshot_path is not None:
-                from repro.obs import append_jsonl_snapshot, strip_samples
-                append_jsonl_snapshot(
-                    hub.config.snapshot_path,
-                    {"step": self.step_count,
-                     "obs": strip_samples(hub.snapshot())})
+            with span("fabric.obs_sample"):
+                hub.sample(self._replica_set, self.engines)
         # Closed loop last, so a decision sees this step's depths and the
         # freshest gauge sample (DESIGN.md §14: signals→decision→actions).
         ctrl = self._control

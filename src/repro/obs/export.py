@@ -1,7 +1,7 @@
 """Exporters for the observability plane (DESIGN.md §13).
 
-Three output formats, all built from the same flight-recorder event tuples
-and gauge sweeps:
+Two output formats, built from the flight-recorder event tuples and gauge
+sweeps:
 
   * :func:`perfetto_trace` — Chrome/Perfetto ``trace.json`` (the Trace
     Event Format): each traced envelope becomes a chain of complete
@@ -11,8 +11,6 @@ and gauge sweeps:
     Control events render as instants ("ph":"i"). pid = host, tid = replica.
   * :func:`prometheus_text` — Prometheus text exposition (``# HELP`` /
     ``# TYPE`` + samples) over the fabric stats dict and a gauge sweep.
-  * :func:`append_jsonl_snapshot` — periodic JSONL snapshots (one JSON
-    object per line, raw latency reservoirs stripped) into ``reports/``.
 
 Plus :func:`stage_breakdown`, the measured per-stage latency table the
 obs bench reports (where do the p99 milliseconds actually go?).
@@ -22,7 +20,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from typing import Dict, List, Optional
 
 from repro.obs.recorder import CONTROL_EVENTS, LIFECYCLE_STAGES
@@ -248,29 +245,3 @@ def prometheus_text(stats, gauges: Optional[dict] = None) -> str:
             v = f"{value:.9g}" if isinstance(value, float) else str(value)
             lines.append(f"{name}{labels} {v}")
     return "\n".join(lines) + "\n"
-
-
-# ---------------------------------------------------------------------------
-# JSONL snapshots
-# ---------------------------------------------------------------------------
-
-def strip_samples(obj):
-    """Deep-copy ``obj`` without raw latency reservoirs (they are exact-
-    merge plumbing, not snapshot payload — DESIGN.md §13 size convention)."""
-    if isinstance(obj, dict):
-        return {k: strip_samples(v) for k, v in obj.items()
-                if k != "latency_samples"}
-    if isinstance(obj, (list, tuple)):
-        return [strip_samples(v) for v in obj]
-    return obj
-
-
-def append_jsonl_snapshot(path: str, snapshot: dict, *,
-                          t: Optional[float] = None) -> None:
-    """Append one snapshot line to a JSONL file (parents created)."""
-    d = os.path.dirname(path)
-    if d:
-        os.makedirs(d, exist_ok=True)
-    rec = {"t": time.time() if t is None else t, **strip_samples(snapshot)}
-    with open(path, "a") as f:
-        f.write(json.dumps(rec) + "\n")

@@ -16,16 +16,23 @@ class-level ``_obs = None`` default (so un-attached fabrics pay one
 ``is None`` check), and :meth:`attach` re-walks the object graph after any
 operation that rebuilds replicas or engines (open / restore / resize /
 fail_host).
+
+While attached, the hub also wraps every garbage-collector pause in a
+``host.gc`` span (:class:`~repro.obs.recorder.GcSpans`); :meth:`close`
+(``Fabric.close``) removes that callback.
 """
 
 from __future__ import annotations
 
+import gc
 import time
+import weakref
 from collections import deque
 from typing import Deque, Dict, List, Tuple
 
 from repro.obs.gauges import sample_fabric_gauges
-from repro.obs.recorder import PRODUCER_RID, FlightRecorder, ObsConfig
+from repro.obs.recorder import (PRODUCER_RID, FlightRecorder, GcSpans,
+                                ObsConfig)
 from repro.sched.stats import LatencyWindow
 
 
@@ -37,6 +44,7 @@ class MetricsHub:
         self.rtt: Dict[int, LatencyWindow] = {}  # dest host -> histogram
         self._window: Deque[Tuple[float, dict]] = deque()
         self.samples_taken = 0
+        self._gc_spans = None  # weakref.finalize that removes the callback
 
     # ---------------------------------------------------------- recorders
     def recorder(self, rid: int = PRODUCER_RID, host: int = 0
@@ -82,12 +90,21 @@ class MetricsHub:
             for v in r.views:
                 v._obs = rec
         replica_set.transport._obs = self
+        if self._gc_spans is None:
+            cb = GcSpans()
+            gc.callbacks.append(cb)
+            self._gc_spans = weakref.finalize(self, cb.remove)
         for eng in engines:
             rec = self.recorder(eng.sched.rid, eng.sched.addr.host)
             eng._obs = rec
             ring = getattr(eng, "admission_ring", None)
             if ring is not None:
                 ring._obs = rec
+
+    def close(self) -> None:
+        """Remove the ``host.gc`` callback (idempotent)."""
+        if self._gc_spans is not None:
+            self._gc_spans()
 
     # ------------------------------------------------------ rolling window
     def sample(self, replica_set, engines=()) -> dict:

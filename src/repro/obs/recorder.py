@@ -19,11 +19,20 @@ kernel calls, flushes) are rare by construction and always recorded.
 Event tuples are ``(t, stage, cls, seq, rid, host, arg)`` — ``t`` from the
 same monotonic clock as the admission-latency stamps, so exporter-built
 spans and the latency reservoirs agree on durations.
+
+Phases of the served step are profiler spans (:func:`span`), not ring
+events: each is a ``jax.profiler.TraceAnnotation`` that lands in the
+profiler's trace beside the device's ops, and carries ``t_mono_ns``, this
+recorder's clock at its start. The median of (trace time - ``t_mono_ns``)
+over a trace's spans maps every ring event onto the device's timeline.
+Outside a profiler session a span records nothing.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -55,6 +64,25 @@ LIFECYCLE_STAGES: Tuple[str, ...] = (
 CONTROL_EVENTS: Tuple[str, ...] = (STEAL, REQUEUE, RESCUE, CLAIM_BLOCK,
                                    FLUSH, CONTROL)
 
+#: Span taxonomy (DESIGN.md §13), outermost first. The names are the wire
+#: strings the trace reduction reads; emit sites use the literals.
+SPAN_NAMES: Tuple[str, ...] = (
+    "fabric.step",        # the whole of Fabric.step
+    "fabric.obs_sample",  # the hub's gauge sweep inside it
+    "engine.step",        # one Engine.step (arg: rid)
+    "engine.admit",       # Engine._admit
+    "engine.drain",       # the policy drain or device-ring claim
+    "engine.alloc",       # a page grab, through its host read
+    "engine.prefill",     # one request laned (arg: prompt_len)
+    "engine.grow_pages",  # Engine._grow_pages
+    "engine.decode",      # the decode forward and its host reads
+    "engine.bookkeep",    # per-lane loop, completions, retirement
+    "host.gc",            # a collector pause (arg: generation)
+)
+
+#: what an emit site enters when no recorder is attached
+NO_SPAN = contextlib.nullcontext()
+
 #: rid used for fabric-global (producer-side / shard-side) rings — events
 #: emitted by code that is not pinned to one replica's drain loop.
 PRODUCER_RID = -1
@@ -76,8 +104,6 @@ class ObsConfig:
       metrics_window_s: rolling gauge-sample retention for the
         :class:`~repro.obs.hub.MetricsHub` window (the autoscaler's input).
       sample_every_n_steps: gauge-sweep cadence in ``Fabric.step`` calls.
-      snapshot_path: optional JSONL file; when set, every gauge sweep also
-        appends one snapshot line (``reports/…``-style periodic export).
     """
 
     enabled: bool = True
@@ -85,7 +111,6 @@ class ObsConfig:
     ring_capacity: int = 4096
     metrics_window_s: float = 60.0
     sample_every_n_steps: int = 16
-    snapshot_path: Optional[str] = None
 
     def validate(self) -> None:
         if not (0.0 <= self.trace_rate <= 1.0):
@@ -114,6 +139,41 @@ def sample_stride(trace_rate: float) -> int:
     return max(1, int(round(1.0 / trace_rate)))
 
 
+def span(name: str, **args):
+    """A profiler span named ``name`` on this module's clock: the
+    ``TraceAnnotation`` carries ``t_mono_ns`` (``time.monotonic_ns()`` at
+    its creation) and ``args`` as event stats. Enter it with ``with``."""
+    from jax import profiler
+    return profiler.TraceAnnotation(name, t_mono_ns=time.monotonic_ns(),
+                                    **args)
+
+
+class GcSpans:
+    """A ``gc.callbacks`` entry that wraps each collector pause in a
+    ``host.gc`` span. It holds no reference to its owner, so an owner that
+    is never closed can still be collected (and its finalizer removes it)."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self):
+        self._open: List[Any] = []
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = span("host.gc", generation=info["generation"])
+            ann.__enter__()
+            self._open.append(ann)
+        elif self._open:
+            self._open.pop().__exit__(None, None, None)
+
+    def remove(self) -> None:
+        """Out of ``gc.callbacks``; a pause left open is closed."""
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+        while self._open:
+            self._open.pop().__exit__(None, None, None)
+
+
 class FlightRecorder:
     """One fixed-size event ring (per replica, or the producer-side ring).
 
@@ -135,6 +195,8 @@ class FlightRecorder:
         self._idx = 0
         self.dropped = 0  # events overwritten by ring wrap
         self.counts: Dict[str, int] = {}  # per-stage emitted totals
+
+    span = staticmethod(span)
 
     def sampled(self, seq: int) -> bool:
         """O(1) head-sampling decision, a pure function of the class cycle

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.obs.recorder import NO_SPAN
 from repro.sched import Envelope, QueueClass, ReplicaSet, Scheduler
 from repro.serving.admission import DeviceAdmissionRing, resolve_device_admission
 from repro.serving.kv_cache import PagedKVPool
@@ -179,16 +180,23 @@ class Engine:
         envs = self.sched.submit_many(name, reqs)
         return [r.uid if e is not None else None for r, e in zip(reqs, envs)]
 
+    def _span(self, name: str, **args):
+        """A profiler span on the attached flight recorder's clock
+        (DESIGN.md §13); a no-op context when none is attached."""
+        rec = self._obs
+        return NO_SPAN if rec is None else rec.span(name, **args)
+
     # ---------------------------------------------------------------- pages
     def _alloc_pages(self, n: int) -> Optional[np.ndarray]:
         if n == 0:
             return np.zeros((0,), np.int32)
-        ids, valid = self.pool.alloc(n)
-        ids, valid = np.asarray(ids), np.asarray(valid)
-        if not valid.all():
-            self.pool.retire(jnp.asarray(ids))  # return partial grab
-            return None
-        return ids
+        with self._span("engine.alloc"):
+            ids, valid = self.pool.alloc(n)
+            ids, valid = np.asarray(ids), np.asarray(valid)
+            if not valid.all():
+                self.pool.retire(jnp.asarray(ids))  # return partial grab
+                return None
+            return ids
 
     def _retire_request(self, lane: int) -> None:
         used = (int(self.seq_lens[lane]) + self.page_size - 1) // self.page_size
@@ -286,7 +294,8 @@ class Engine:
         # (batched dequeue_many claims under the hood, strict FIFO per class);
         # on the ring path the batch instead comes out of one fused device
         # claim over the prefetched entries.
-        batch = self._drain_admission(len(free))
+        with self._span("engine.drain"):
+            batch = self._drain_admission(len(free))
         for idx, (lane, (qc, env)) in enumerate(zip(free, batch)):
             req: Request = env.payload
             need = (len(req.prompt) + self.page_size - 1) // self.page_size
@@ -302,23 +311,25 @@ class Engine:
                 pages = self._alloc_pages(max(1, need))
             self.active[lane] = req
             self._lane_env[lane] = (qc, env)
-            self.block_tables = self.block_tables.at[lane, :len(pages)].set(
-                jnp.asarray(pages))
-            self.seq_lens = self.seq_lens.at[lane].set(0)
-            # prefill: process the whole prompt at once (same compiled
-            # callable as decode, traced at the prompt length)
-            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            bt = self.block_tables[lane:lane + 1]
-            sl = jnp.zeros((1,), jnp.int32)
-            logits, self.pool.k_pages, self.pool.v_pages = self._forward(
-                self.params, toks, self.pool.k_pages, self.pool.v_pages, bt, sl)
-            tok = int(jnp.argmax(logits[0]))
-            self.seq_lens = self.seq_lens.at[lane].set(len(req.prompt))
-            self.last_tok = self.last_tok.at[lane].set(tok)
-            req.output.append(tok)
-            rec = self._obs
-            if rec is not None and rec.sampled(env.seq):
-                rec.emit("lane_prefill", qc.name, env.seq, arg=lane)
+            with self._span("engine.prefill", prompt_len=len(req.prompt)):
+                self.block_tables = self.block_tables.at[
+                    lane, :len(pages)].set(jnp.asarray(pages))
+                self.seq_lens = self.seq_lens.at[lane].set(0)
+                # prefill: process the whole prompt at once (same compiled
+                # callable as decode, traced at the prompt length)
+                toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                bt = self.block_tables[lane:lane + 1]
+                sl = jnp.zeros((1,), jnp.int32)
+                logits, self.pool.k_pages, self.pool.v_pages = self._forward(
+                    self.params, toks, self.pool.k_pages, self.pool.v_pages,
+                    bt, sl)
+                tok = int(jnp.argmax(logits[0]))
+                rec = self._obs
+                if rec is not None and rec.sampled(env.seq):
+                    rec.emit("lane_prefill", qc.name, env.seq, arg=lane)
+                self.seq_lens = self.seq_lens.at[lane].set(len(req.prompt))
+                self.last_tok = self.last_tok.at[lane].set(tok)
+                req.output.append(tok)
 
     def _grow_pages(self) -> None:
         """Allocate fresh pages for every lane whose next token crosses a page
@@ -367,25 +378,41 @@ class Engine:
 
     # ---------------------------------------------------------------- step
     def step(self) -> List[Request]:
-        """One engine iteration: tick window clock, reclaim, admit, decode."""
+        """One engine iteration: tick window clock, reclaim, admit, decode.
+        With a recorder attached the step and each of its phases are
+        profiler spans (``engine.*``, DESIGN.md §13)."""
+        rec = self._obs
+        with NO_SPAN if rec is None else rec.span("engine.step", rid=rec.rid):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         self.step_count += 1
         self.pool.tick(self.step_count)
-        self._admit()
-        self._grow_pages()
+        with self._span("engine.admit"):
+            self._admit()
+        with self._span("engine.grow_pages"):
+            self._grow_pages()
         active_np = np.array([r is not None for r in self.active])
         if not active_np.any():
             return []
-        # Decode all lanes in one call on the device-resident tables.
-        logits, self.pool.k_pages, self.pool.v_pages = self._forward(
-            self.params, self.last_tok[:, None], self.pool.k_pages,
-            self.pool.v_pages, self.block_tables, self.seq_lens)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        mask = jnp.asarray(active_np)
-        self.seq_lens = self.seq_lens + mask.astype(jnp.int32)
-        self.last_tok = jnp.where(mask, nxt, self.last_tok)
-        # single host sync per step for completion bookkeeping
-        nxt_np = np.asarray(nxt)
-        sl_np = np.asarray(self.seq_lens)
+        with self._span("engine.decode"):
+            # Decode all lanes in one call on the device-resident tables.
+            logits, self.pool.k_pages, self.pool.v_pages = self._forward(
+                self.params, self.last_tok[:, None], self.pool.k_pages,
+                self.pool.v_pages, self.block_tables, self.seq_lens)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            mask = jnp.asarray(active_np)
+            self.seq_lens = self.seq_lens + mask.astype(jnp.int32)
+            self.last_tok = jnp.where(mask, nxt, self.last_tok)
+            # single host sync per step for completion bookkeeping
+            nxt_np = np.asarray(nxt)
+            sl_np = np.asarray(self.seq_lens)
+        with self._span("engine.bookkeep"):
+            return self._bookkeep(active_np, nxt_np, sl_np)
+
+    def _bookkeep(self, active_np, nxt_np, sl_np) -> List[Request]:
+        """Append each decoding lane's token; complete and retire the lanes
+        that reached their length."""
         done = []
         rec = self._obs
         for lane in np.nonzero(active_np)[0]:

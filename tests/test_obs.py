@@ -8,7 +8,7 @@ import pytest
 from repro.obs import (CONTROL_EVENTS, LIFECYCLE_STAGES, PRODUCER_RID,
                        FlightRecorder, MetricsHub, ObsConfig,
                        format_class_lines, perfetto_trace, prometheus_text,
-                       sample_stride, stage_breakdown, strip_samples)
+                       sample_stride, stage_breakdown)
 from repro.sched import QueueClass
 
 
@@ -188,12 +188,6 @@ def test_prometheus_text_is_well_formed():
     assert "repro_obs_events_dropped" in types
 
 
-def test_strip_samples_removes_reservoirs_deeply():
-    obj = {"a": {"latency_samples": [1, 2], "keep": 1},
-           "b": [{"latency_samples": []}, 3]}
-    assert strip_samples(obj) == {"a": {"keep": 1}, "b": [{}, 3]}
-
-
 def test_format_class_lines_handles_missing_latency():
     from repro.fabric import Fabric, FabricConfig
     fab = Fabric.open(FabricConfig())
@@ -309,18 +303,3 @@ def test_fabric_config_obs_json_round_trip():
     assert isinstance(again.obs, ObsConfig)
     with pytest.raises(FabricConfigError):
         FabricConfig(obs=ObsConfig(trace_rate=7.0))
-
-
-def test_jsonl_snapshot_cadence(tmp_path):
-    from repro.fabric import Fabric, FabricConfig
-    path = str(tmp_path / "obs" / "snapshots.jsonl")
-    cfg = FabricConfig(obs=ObsConfig(sample_every_n_steps=2,
-                                     snapshot_path=path))
-    fab = Fabric.open(cfg)
-    fab.submit_many(list(range(64)))
-    fab.drain()
-    lines = [json.loads(l) for l in open(path)]
-    assert len(lines) >= 2  # one line per cadence hit
-    for rec in lines:
-        assert "t" in rec and "obs" in rec and "step" in rec
-        assert "latency_samples" not in json.dumps(rec)
