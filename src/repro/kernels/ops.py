@@ -35,10 +35,18 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=0,
     return out.transpose(0, 2, 1, 3)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, seq_lens):
-    """q [B, H, hd]; pages [P, KV, page, hd] -> [B, H, hd]."""
-    return _pa.paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
+def paged_attention(q, k_new, v_new, k_pages, v_pages, cached_lens, sched,
+                    layer=0):
+    """Decode attention of each lane's new token over its cached pages and
+    itself: q [B, H, hd]; k/v_new [B, KV, hd]; pages [L, P, KV, page, hd],
+    read at ``layer``; ``sched`` from ``paged_schedule``, one per call of
+    the model -> [B, H, hd]."""
+    return _pa.paged_attention(q, k_new, v_new, k_pages, v_pages,
+                               cached_lens, sched, layer,
                                interpret=not on_tpu())
+
+
+paged_schedule = _pa.schedule
 
 
 _ref_ring_jit = None

@@ -6,21 +6,34 @@ monotone cycles, retired when a request finishes, and reclaimed only outside
 the protection window — so a page referenced by an in-flight decode step can
 never be recycled underneath it (the paper's UAF guarantee, transplanted).
 
-TPU adaptation: instead of CUDA-style gather loads, the page indirection uses
-*scalar prefetch* — block tables are SMEM-prefetched scalars consumed by the
-BlockSpec index_map, so the pipeline DMAs exactly the pages each sequence
-needs from HBM into VMEM. The last grid axis (pages) iterates sequentially,
-carrying the online-softmax state in VMEM scratch.
+Each lane's new token attends over the positions its block table already
+holds and over itself; the kernel reads only those live pages, straight
+from the pool, and takes the new token's k/v from its arguments (the model
+writes them into the pool after the call). The grid is a list of (lane,
+block) steps of dynamic length, one per block of ``pages_per_block`` live
+pages, lanes in order (``schedule``, computed once per model call and shared
+by every layer); a lane with nothing cached has one step, for its new token.
+A step reads a whole page (every KV head) through each of
+``pages_per_block`` inputs of the same pool, whose scalar-prefetched index
+map gives that step's page ids. Where a step has fewer live pages than
+inputs, the idle inputs repeat the page they held the step before, so the
+pipeline sees an unchanged block index and copies nothing: each live page is
+read once per call, whatever the query group, and no other page is read.
 
-Layouts: q [B, H, hd] (one decode token); k/v pages [P, KV, page, hd];
-block_tables [B, pages_per_seq] int32; seq_lens [B] int32. Inside the call q
-and the output are viewed as [B, H, 1, hd], so each (1, hd) block spans the
-last two dims whole, as the TPU block layout requires.
+Numerics: bf16 (or float32) operands on the MXU with float32 accumulation,
+and an online softmax in float32. GQA grouping is r-major, as in the model
+(query head ``h`` reads KV head ``h % KV``).
+
+Layouts: q [B, H, hd]; k/v_new [B, KV, hd]; k/v pages [L, P, KV, page, hd],
+read at ``layer``; block_tables [B, pages_per_seq] int32 (entries past a
+lane's live pages are never read); cached_lens [B] int32, the positions each
+lane holds before this token.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,84 +41,160 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Positions per grid step: 8 pages of 16 tokens.
+BLOCK_POSITIONS = 128
 
 
-def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                  l_ref, *, page: int, sm_scale: float):
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    np_ = pl.num_programs(2)
+class Schedule(NamedTuple):
+    """The grid of one decode call, shared by every layer: ``steps`` live
+    (lane, block) pairs; per step its lane and block, and the page id each
+    of the ``pages_per_block`` inputs holds ([T * pages_per_block])."""
+    steps: jax.Array
+    lane: jax.Array
+    blk: jax.Array
+    pages: jax.Array
 
-    @pl.when(p == 0)
+
+def schedule(block_tables, cached_lens, page: int) -> Schedule:
+    """Every lane has at least one step, where its new token is folded in.
+    The tables are padded to the static bound ``T = B * ceil(pps / ppb)``
+    by repeating the last step. An input with no live page at a step holds
+    the page it held at its previous live step, so the pipeline never
+    copies for it, and every page the pipeline fetches is a live one."""
+    B, pps = block_tables.shape
+    ppb = min(max(1, BLOCK_POSITIONS // page), pps)
+    T = B * pl.cdiv(pps, ppb)
+    npages = jnp.minimum(pl.cdiv(cached_lens, page), pps)
+    nblk = jnp.maximum(pl.cdiv(npages, ppb), 1)
+    ends = jnp.cumsum(nblk)
+    steps = ends[-1]
+    t = jnp.arange(T, dtype=jnp.int32)
+    t_live = jnp.minimum(t, steps - 1)
+    lane = jnp.sum(ends[None, :] <= t_live[:, None], axis=1, dtype=jnp.int32)
+    blk = t_live - (ends[lane] - nblk[lane])
+    pos = blk[:, None] * ppb + jnp.arange(ppb, dtype=jnp.int32)[None, :]
+    live = (t < steps)[:, None] & (pos < npages[lane][:, None])
+    raw = block_tables[lane[:, None], jnp.minimum(pos, pps - 1)]
+    held = jax.lax.cummax(jnp.where(live, t[:, None], -1), axis=0)
+    pages = jnp.take_along_axis(raw, jnp.maximum(held, 0), axis=0)
+    # before its first live step an input holds the page it will first
+    # read; one that is never live holds the call's first live page
+    first = jnp.take_along_axis(raw, jnp.argmax(live, axis=0)[None], axis=0)
+    first = jnp.where(live.any(axis=0), first[0], raw.reshape(-1)[
+        jnp.argmax(live.reshape(-1))])
+    pages = jnp.where(held < 0, first[None, :], pages)
+    return Schedule(steps, lane, blk.astype(jnp.int32),
+                    pages.reshape(-1).astype(jnp.int32))
+
+
+def _paged_kernel(layer_ref, lane_ref, blk_ref, pages_ref, cl_ref, q_ref,
+                  kn_ref, vn_ref, *refs, ppb: int, page: int, sm_scale: float):
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb:]
+    t = pl.program_id(0)
+    blk = blk_ref[t]
+    cached = cl_ref[lane_ref[t]]
+    bk = ppb * page
+
+    @pl.when(blk == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    seq_len = sl_ref[b]
+    q = q_ref[...]                                              # [KV, rep, hd]
 
-    @pl.when(p * page < seq_len)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)                       # [1, hd]
-        k = k_ref[...].astype(jnp.float32)                       # [page, hd]
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale  # [1, page]
-        pos = p * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        mask = pos < seq_len
-        s = jnp.where(mask, s, NEG_INF)
+    def online(s, v):
+        """Fold scores s [KV, rep, n] over values v [KV, n, hd] into the
+        running max, sum and accumulator."""
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(pr, v)
+        p = jnp.where(s > NEG_INF, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "grt,gtd->grd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(p == np_ - 1)
-    def _out():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
-            o_ref.dtype)
+    @pl.when(blk * bk < cached)
+    def _pages():
+        k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [KV, bk, hd]
+        v = jnp.concatenate([r[...] for r in v_refs], axis=1)
+        s = jnp.einsum("grd,gtd->grt", q, k,
+                       preferred_element_type=jnp.float32) * sm_scale
+        pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        online(jnp.where(pos < cached, s, NEG_INF), v)
+
+    @pl.when((blk + 1) * bk >= cached)
+    def _new_token():
+        kn = kn_ref[...]                                        # [KV, 1, hd]
+        s = jnp.sum(q.astype(jnp.float32) * kn.astype(jnp.float32), axis=-1,
+                    keepdims=True) * sm_scale                   # [KV, rep, 1]
+        online(s, vn_ref[...])
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(
     q: jax.Array,             # [B, H, hd]
-    k_pages: jax.Array,       # [P, KV, page, hd]
-    v_pages: jax.Array,       # [P, KV, page, hd]
-    block_tables: jax.Array,  # [B, pages_per_seq] int32 (pad with any valid id)
-    seq_lens: jax.Array,      # [B] int32
+    k_new: jax.Array,         # [B, KV, hd]
+    v_new: jax.Array,         # [B, KV, hd]
+    k_pages: jax.Array,       # [L, P, KV, page, hd]
+    v_pages: jax.Array,
+    cached_lens: jax.Array,   # [B] int32
+    sched: Schedule,          # schedule(block_tables, cached_lens, page)
+    layer: jax.Array | int = 0,
     *,
     interpret: bool = False,
 ) -> jax.Array:
+    """Each lane's new token (q, k_new, v_new) attends over the
+    ``cached_lens[b]`` positions its block table holds in layer ``layer`` of
+    the pool, and over itself. Returns [B, H, hd]."""
     B, H, hd = q.shape
-    P, KV, page, _ = k_pages.shape
-    pps = block_tables.shape[1]
-    sm_scale = 1.0 / (hd ** 0.5)
+    KV, page = k_pages.shape[2:4]
+    rep = H // KV
+    ppb = sched.pages.shape[0] // sched.lane.shape[0]
+    # r-major groups: head h = r * KV + g reads KV head g
+    qg = q.reshape(B, rep, KV, hd).transpose(0, 2, 1, 3)       # [B, KV, rep, hd]
+    kn = k_new.astype(k_pages.dtype)[:, :, None]                # [B, KV, 1, hd]
+    vn = v_new.astype(v_pages.dtype)[:, :, None]
 
-    kernel = functools.partial(_paged_kernel, page=page, sm_scale=sm_scale)
+    def lane_block(rows):
+        return pl.BlockSpec((None, KV, rows, hd),
+                            lambda t, ly, ln, bl, pg, cl: (ln[t], 0, 0, 0))
+
+    def page_block(j):
+        return pl.BlockSpec(
+            (None, None, KV, page, hd),
+            lambda t, ly, ln, bl, pg, cl: (ly[0], pg[t * ppb + j], 0, 0, 0))
+
+    if not interpret:
+        # The pool stays in HBM: unconstrained, XLA may stage the whole pool
+        # in VMEM before the call, a copy of every page, live or not.
+        k_pages = pltpu.with_memory_space_constraint(k_pages, pltpu.HBM)
+        v_pages = pltpu.with_memory_space_constraint(v_pages, pltpu.HBM)
+    kernel = functools.partial(_paged_kernel, ppb=ppb, page=page,
+                               sm_scale=1.0 / (hd ** 0.5))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, pps),
-        in_specs=[
-            pl.BlockSpec((None, None, 1, hd),
-                         lambda b, h, p, bt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, page, hd),
-                         lambda b, h, p, bt, sl: (bt[b, p], h % KV, 0, 0)),
-            pl.BlockSpec((None, None, page, hd),
-                         lambda b, h, p, bt, sl: (bt[b, p], h % KV, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, 1, hd),
-                               lambda b, h, p, bt, sl: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
+        num_scalar_prefetch=5,
+        grid=(sched.steps,),
+        in_specs=([lane_block(rep), lane_block(1), lane_block(1)]
+                  + [page_block(j) for j in range(ppb)] * 2),
+        out_specs=lane_block(rep),
+        scratch_shapes=[pltpu.VMEM((KV, rep, 1), jnp.float32),
+                        pltpu.VMEM((KV, rep, 1), jnp.float32),
+                        pltpu.VMEM((KV, rep, hd), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), q.dtype),
+        name="paged_decode_attention",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_tables, seq_lens, q.reshape(B, H, 1, hd), k_pages, v_pages)
-    return out.reshape(B, H, hd)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), sched.lane, sched.blk,
+      sched.pages, cached_lens, qg, kn, vn, *([k_pages] * ppb),
+      *([v_pages] * ppb))
+    return out.transpose(0, 2, 1, 3).reshape(B, H, hd)

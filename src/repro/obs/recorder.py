@@ -75,7 +75,8 @@ SPAN_NAMES: Tuple[str, ...] = (
     "engine.alloc",       # a page grab, through its host read
     "engine.prefill",     # one request laned (arg: prompt_len)
     "engine.grow_pages",  # Engine._grow_pages
-    "engine.decode",      # the decode forward and its host reads
+    "engine.decode",      # the decode forward and its host reads (arg:
+                          # attn, "kernel" or "gather")
     "engine.bookkeep",    # per-lane loop, completions, retirement
     "host.gc",            # a collector pause (arg: generation)
 )

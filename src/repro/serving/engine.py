@@ -39,6 +39,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.obs.recorder import NO_SPAN
 from repro.sched import Envelope, QueueClass, ReplicaSet, Scheduler
+from repro.serving import paged_model
 from repro.serving.admission import DeviceAdmissionRing, resolve_device_admission
 from repro.serving.kv_cache import PagedKVPool
 from repro.serving.paged_model import make_paged_forward
@@ -120,6 +121,10 @@ class Engine:
         # sequence lengths — one jit, one compilation cache. Replicas pass a
         # shared callable so N engines share one compilation cache.
         self._forward = forward_fn or make_paged_forward(cfg)
+        # which attention the decode step's forward takes (the engine.decode
+        # span's ``attn``): the paged kernel or the whole-table gather
+        self._decode_attn = ("kernel" if paged_model.kernel_attention(1, cfg)
+                             else "gather")
         # Device-resident admission (DESIGN.md §12): policy-drained batches
         # route through a bounded CMP ring on the accelerator — one fused
         # reclaim+enqueue+claim+publish invocation per step. "auto" enables
@@ -395,7 +400,7 @@ class Engine:
         active_np = np.array([r is not None for r in self.active])
         if not active_np.any():
             return []
-        with self._span("engine.decode"):
+        with self._span("engine.decode", attn=self._decode_attn):
             # Decode all lanes in one call on the device-resident tables.
             logits, self.pool.k_pages, self.pool.v_pages = self._forward(
                 self.params, self.last_tok[:, None], self.pool.k_pages,

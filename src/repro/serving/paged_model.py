@@ -6,9 +6,14 @@ Pages allocated to a request are *sequential in position* (page j covers
 positions [j*page, (j+1)*page)), so the gathered page sequence is position-
 ordered and the attention mask is a simple length mask.
 
-The gather formulation lowers to XLA gathers (shardable); on TPU the
-``repro.kernels.paged_attention`` Pallas kernel implements the same op with
-scalar-prefetch DMA (validated against the same oracle).
+Two paths, chosen by ``kernel_attention`` from the call's shape and the
+model alone. Decode (one token per lane) on a TPU reads each lane's live
+pages straight from the pool the call received, through the
+``repro.kernels.paged_attention`` Pallas kernel, and writes every layer's
+new token into the pool after the layer loop. Everything
+else (prefill, softcapped logits, hosts without a TPU) writes each layer's
+tokens into its page slice and gathers each lane's whole page table, masked
+to its length (``_gathered_attention``).
 """
 
 from __future__ import annotations
@@ -19,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import model as M
@@ -61,14 +67,18 @@ def _gathered_attention(q, k_pages, v_pages, block_tables, positions, seq_lens,
     return L.cache_attention(q, kg, vg, positions, k_pos, softcap=softcap)
 
 
-def _paged_block(x, p, cfg: ModelConfig, kind: str, k_pages, v_pages,
-                 block_tables, positions, seq_lens):
+def kernel_attention(S: int, cfg: ModelConfig) -> bool:
+    """True where attention over ``S`` new tokens per lane takes the paged
+    decode kernel: one token (decode), no logit softcap, on a TPU."""
+    return S == 1 and cfg.attn_softcap == 0.0 and kops.on_tpu()
+
+
+def _paged_block(x, p, cfg: ModelConfig, kind: str, positions, attend):
+    """One block; ``attend(q, k_new, v_new) -> (attn, out)`` reads (and may
+    write) the KV pages, and its ``out`` is handed back with ``x``."""
     h_in = L.norm(x, p["ln1"], cfg.norm)
     q, k_new, v_new = _proj_qkv(h_in, p["attn"], cfg, positions)
-    k_pages, v_pages = _scatter_pages(k_pages, v_pages, k_new, v_new,
-                                      block_tables, positions)
-    attn = _gathered_attention(q, k_pages, v_pages, block_tables, positions,
-                               seq_lens, cfg.attn_softcap)
+    attn, out = attend(q, k_new, v_new)
     B, S = x.shape[0], x.shape[1]
     attn = attn.reshape(B, S, cfg.num_heads * cfg.resolved_head_dim) @ p["attn"]["wo"]
     x = x + attn
@@ -80,6 +90,89 @@ def _paged_block(x, p, cfg: ModelConfig, kind: str, k_pages, v_pages,
         x = x + y
     else:
         x = x + L.swiglu(L.norm(x, p["ln2"], cfg.norm), p["mlp"], cfg.act)
+    return x, out
+
+
+def _gathered_layers(x, params, cfg: ModelConfig, k_pages, v_pages,
+                     block_tables, positions, seq_lens):
+    """Each layer writes its tokens into its page slice, then gathers every
+    lane's whole table; the scan stacks the written slices."""
+    def step(x, xs):
+        layer_p, kp, vp = xs
+        new_kp, new_vp = [], []
+        for j, kind in enumerate(cfg.block_pattern):
+            def attend(q, k_new, v_new, kp=kp[j], vp=vp[j]):
+                kp, vp = _scatter_pages(kp, vp, k_new, v_new, block_tables,
+                                        positions)
+                return _gathered_attention(q, kp, vp, block_tables, positions,
+                                           seq_lens, cfg.attn_softcap), (kp, vp)
+            x, (nk, nv) = _paged_block(x, layer_p[str(j)], cfg, kind,
+                                       positions, attend)
+            new_kp.append(nk)
+            new_vp.append(nv)
+        return x, (jnp.stack(new_kp), jnp.stack(new_vp))
+
+    r, n_pat = cfg.pattern_repeats, len(cfg.block_pattern)
+    kp_s = k_pages.reshape((r, n_pat) + k_pages.shape[1:])
+    vp_s = v_pages.reshape((r, n_pat) + v_pages.shape[1:])
+    x, (new_kp, new_vp) = jax.lax.scan(step, x, (params["blocks"], kp_s, vp_s))
+    return x, new_kp.reshape(k_pages.shape), new_vp.reshape(v_pages.shape)
+
+
+def _kernel_decode_layers(x, params, cfg: ModelConfig, k_pages, v_pages,
+                          block_tables, positions, seq_lens):
+    """Decode: each layer's kernel reads the pool as the call received it
+    (the lanes' earlier tokens) plus the step's own token; after the layer
+    loop every layer's token is written, one slice update per lane. (A slice
+    of the scan's stacked output would be copied whole for the kernel, every
+    layer.)"""
+    n_pat = len(cfg.block_pattern)
+    sched = kops.paged_schedule(block_tables, seq_lens, k_pages.shape[3])
+    # A pool whose head_dim fills whole 128-lane tiles is stored page by
+    # page, and the kernel reads it where it lies. Otherwise XLA stores it
+    # with the page index minor (no padding; phi3-mini's head_dim 96), so no
+    # page is contiguous: the kernel then reads each layer's slice, which
+    # XLA relayouts layer by layer (the whole pool at once does not fit
+    # beside phi3-mini's weights).
+    in_place = k_pages.shape[-1] % 128 == 0
+
+    def step(x, xs):
+        layer_p, i = xs
+        new_k, new_v = [], []
+        for j, kind in enumerate(cfg.block_pattern):
+            def attend(q, k_new, v_new, layer=i * n_pat + j):
+                kp, vp, at = ((k_pages, v_pages, layer) if in_place else
+                              (jax.lax.dynamic_slice_in_dim(k_pages, layer, 1),
+                               jax.lax.dynamic_slice_in_dim(v_pages, layer, 1),
+                               0))
+                with jax.named_scope("paged_decode_attention"):
+                    attn = kops.paged_attention(
+                        q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp, seq_lens,
+                        sched, at)
+                return attn[:, None], (k_new[:, 0], v_new[:, 0])
+            x, (nk, nv) = _paged_block(x, layer_p[str(j)], cfg, kind,
+                                       positions, attend)
+            new_k.append(nk)
+            new_v.append(nv)
+        return x, (jnp.stack(new_k), jnp.stack(new_v))
+
+    r = cfg.pattern_repeats
+    x, (new_k, new_v) = jax.lax.scan(
+        step, x, (params["blocks"], jnp.arange(r, dtype=jnp.int32)))
+    # [r, n_pat, B, KV, hd] -> [L, B, KV, hd]
+    new_k = new_k.reshape((-1,) + new_k.shape[2:]).astype(k_pages.dtype)
+    new_v = new_v.reshape((-1,) + new_v.shape[2:]).astype(v_pages.dtype)
+    pg = k_pages.shape[3]
+    rows = jnp.take_along_axis(block_tables, positions // pg, axis=1)[:, 0]
+    slots = positions[:, 0] % pg
+    # one slice update per lane keeps the pool in the layout it came in; a
+    # scatter here made XLA relayout the whole pool, twice
+    for b in range(x.shape[0]):
+        at = (0, rows[b], 0, slots[b], 0)
+        k_pages = jax.lax.dynamic_update_slice(
+            k_pages, new_k[:, b, :, None, None].swapaxes(1, 2), at)
+        v_pages = jax.lax.dynamic_update_slice(
+            v_pages, new_v[:, b, :, None, None].swapaxes(1, 2), at)
     return x, k_pages, v_pages
 
 
@@ -95,27 +188,16 @@ def paged_forward(params, tokens, cfg: ModelConfig, k_pages, v_pages,
     attn_kinds = [k for k in cfg.block_pattern if k in ("dense", "moe")]
     assert len(attn_kinds) == len(cfg.block_pattern), (
         "paged serving supports attention-based families only")
-
-    def step(carry, xs):
-        x = carry
-        layer_p, kp, vp = xs
-        new_kp, new_vp = [], []
-        for j, kind in enumerate(cfg.block_pattern):
-            x, nk, nv = _paged_block(x, layer_p[str(j)], cfg, kind,
-                                     kp[j], vp[j], block_tables,
-                                     positions, seq_lens + S)
-            new_kp.append(nk)
-            new_vp.append(nv)
-        return x, (jnp.stack(new_kp), jnp.stack(new_vp))
-
-    r = cfg.pattern_repeats
-    n_pat = len(cfg.block_pattern)
-    kp_s = k_pages.reshape((r, n_pat) + k_pages.shape[1:])
-    vp_s = v_pages.reshape((r, n_pat) + v_pages.shape[1:])
-    x, (new_kp, new_vp) = jax.lax.scan(step, x, (params["blocks"], kp_s, vp_s))
+    if kernel_attention(S, cfg):
+        x, k_pages, v_pages = _kernel_decode_layers(
+            x, params, cfg, k_pages, v_pages, block_tables, positions, seq_lens)
+    else:
+        x, k_pages, v_pages = _gathered_layers(
+            x, params, cfg, k_pages, v_pages, block_tables, positions,
+            seq_lens + S)
     x = L.norm(x, params["final_norm"], cfg.norm)
     logits = M._logits(x[:, -1:], params, cfg)[:, 0]
-    return logits, new_kp.reshape(k_pages.shape), new_vp.reshape(v_pages.shape)
+    return logits, k_pages, v_pages
 
 
 def make_paged_forward(cfg: ModelConfig):

@@ -77,17 +77,40 @@ def test_claim_compiles(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compile_decode_kernel(one_chip, cfg, batch, pages_shape, pps, layer):
+    """The decode kernel with its schedule, as one layer of the served step
+    calls it, compiled for one chip."""
+    hd, bf = cfg.resolved_head_dim, jnp.bfloat16
+    kv = (batch, cfg.num_kv_heads, hd)
+    fn = jax.jit(lambda q, kn, vn, kp, vp, bt, cl: paged_attention.paged_attention(
+        q, kn, vn, kp, vp, cl, paged_attention.schedule(bt, cl, pages_shape[-2]),
+        layer))
+    return fn.lower(
+        _spec(one_chip, (batch, cfg.num_heads, hd), bf), _spec(one_chip, kv, bf),
+        _spec(one_chip, kv, bf), _spec(one_chip, pages_shape, bf),
+        _spec(one_chip, pages_shape, bf), _spec(one_chip, (batch, pps)),
+        _spec(one_chip, (batch,))).compile()
+
+
 def test_paged_attention_compiles_at_yi6b_widths(one_chip):
+    """GQA 32/4 at head_dim 128, reading layer 5 of the whole stacked pool
+    in place, as the served decode step does."""
     cfg = get_config("yi-6b")
     sm = _chip_smoke()
-    hd, bf = cfg.resolved_head_dim, jnp.bfloat16
-    pages = (sm.NUM_PAGES, cfg.num_kv_heads, sm.PAGE_SIZE, hd)
-    fn = jax.jit(paged_attention.paged_attention)
-    compiled = fn.lower(
-        _spec(one_chip, (sm.MAX_BATCH, cfg.num_heads, hd), bf),
-        _spec(one_chip, pages, bf), _spec(one_chip, pages, bf),
-        _spec(one_chip, (sm.MAX_BATCH, sm.MAX_SEQ // sm.PAGE_SIZE)),
-        _spec(one_chip, (sm.MAX_BATCH,))).compile()
+    pages = (cfg.num_layers, sm.NUM_PAGES, cfg.num_kv_heads, sm.PAGE_SIZE,
+             cfg.resolved_head_dim)
+    compiled = _compile_decode_kernel(one_chip, cfg, sm.MAX_BATCH, pages,
+                                      sm.MAX_SEQ // sm.PAGE_SIZE, 5)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_compiles_at_phi3_widths(one_chip):
+    """MHA 32/32 at head_dim 96 (not a multiple of the 128-lane tile), one
+    layer's slice of the pool as the served step passes it, at phi3.decode's
+    serving geometry: 8 lanes, 384 pages of 16, 2048 positions."""
+    cfg = get_config("phi3-mini-3.8b")
+    pages = (1, 384, cfg.num_kv_heads, 16, cfg.resolved_head_dim)
+    compiled = _compile_decode_kernel(one_chip, cfg, 8, pages, 2048 // 16, 0)
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -103,9 +126,12 @@ def test_flash_attention_compiles_at_yi6b_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_yi6b_decode_step_fits_one_chip(one_chip):
+def test_yi6b_decode_step_fits_one_chip(one_chip, monkeypatch):
     """The engine's decode step at full width, with the pool chip_smoke.py
-    serves from: params + pool + the call's outputs and temporaries fit."""
+    serves from: params + pool + the call's outputs and temporaries fit, and
+    attention is the paged decode kernel, as served on a TPU."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # this host is a CPU
     cfg = get_config("yi-6b")
     sm = _chip_smoke()
     params = jax.eval_shape(lambda k: init_params(cfg, k),
@@ -128,3 +154,4 @@ def test_yi6b_decode_step_fits_one_chip(one_chip):
           f"{mem.temp_size_in_bytes / GiB:.2f} GiB, alias "
           f"{mem.alias_size_in_bytes / GiB:.2f} GiB")
     assert total <= FITS_BYTES, total / GiB
+    assert "tpu_custom_call" in compiled.as_text()

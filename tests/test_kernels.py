@@ -56,24 +56,84 @@ def test_flash_attention_noncausal():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.parametrize("B,H,KV,hd,page,P,pps", [
-    (2, 4, 2, 32, 8, 16, 4),
-    (3, 8, 8, 64, 16, 32, 6),   # MHA pages
-    (1, 16, 2, 64, 32, 8, 2),
+def _decode_case(H, KV, hd, page, pps, dtype, seed=0):
+    """Six lanes, one per length case, each with its own pages: cached
+    lengths 0 (an empty lane whose table points at stale pages, filled with
+    NaN), 1, a page less one token (the new token ends the page), a whole
+    page (the new token opens the next), mid-block, and the whole table (the
+    new token takes its last position). Returns the kernel's inputs and
+    ``pool`` with each new token written where the model writes it."""
+    cached = [0, 1, page - 1, page, 3 * page + page // 2, pps * page - 1]
+    B = len(cached)
+    P = B * pps + 2
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (B, H, hd), dtype)
+    kv_new = jax.random.normal(ks[1], (2, B, KV, hd), dtype)
+    pool = jax.random.normal(ks[2], (2, P, KV, page, hd), dtype)
+    ids = np.asarray(jax.random.permutation(ks[3], P))[:B * pps].reshape(B, pps)
+    bt = jnp.asarray(ids, jnp.int32)
+    cl = jnp.asarray(cached, jnp.int32)
+    rows = np.asarray(bt)[np.arange(B), np.asarray(cl) // page]
+    slots = np.asarray(cl) % page
+    written = pool.at[:, rows, :, slots].set(jnp.moveaxis(kv_new, 1, 0))
+    stale = jnp.zeros(P, bool).at[bt[0]].set(True)
+    poisoned = jnp.where(stale[None, :, None, None, None], jnp.nan, pool)
+    return q, kv_new, poisoned, written, bt, cl
+
+
+@pytest.mark.parametrize("H,KV,hd,page,pps", [
+    (16, 2, 128, 8, 40),        # GQA, a group of 8; up to 3 blocks of 16 pages
+    (4, 4, 96, 16, 20),         # MHA at head_dim 96 (phi3-mini's); 8-page blocks
+    (8, 2, 32, 8, 6),           # GQA 4; one block holds the whole table
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_sweep(B, H, KV, hd, page, P, pps, dtype):
-    ks = jax.random.split(KEY, 5)
-    q = jax.random.normal(ks[0], (B, H, hd), dtype)
-    kp = jax.random.normal(ks[1], (P, KV, page, hd), dtype)
-    vp = jax.random.normal(ks[2], (P, KV, page, hd), dtype)
-    bt = jax.random.randint(ks[3], (B, pps), 0, P, jnp.int32)
-    sl = jax.random.randint(ks[4], (B,), 1, pps * page + 1, jnp.int32)
-    out = ops.paged_attention(q, kp, vp, bt, sl)
-    ref = ref_paged_attention(q, kp, vp, bt, sl)
+def test_paged_attention_sweep(H, KV, hd, page, pps, dtype):
+    from repro.serving.paged_model import _gathered_attention
+    q, (kn, vn), poisoned, written, bt, cl = _decode_case(H, KV, hd, page,
+                                                          pps, dtype)
+    sched = ops.paged_schedule(bt, cl, page)
+    out = ops.paged_attention(q, kn, vn, poisoned[:1], poisoned[1:], cl, sched)
+    out = np.asarray(out, np.float32)
+    ref = ref_paged_attention(q, written[0], written[1], bt, cl + 1)
+    gathered = _gathered_attention(q[:, None], written[0], written[1], bt,
+                                   cl[:, None], cl + 1)[:, 0]
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=tol, rtol=tol)
+    live = slice(1, None)   # the empty lane's gathers read its stale pages
+    for want in (ref, gathered):
+        np.testing.assert_allclose(out[live], np.asarray(want, np.float32)[live],
+                                   atol=tol, rtol=tol)
+    # The empty lane attends to its new token alone, and read no page.
+    rep = H // KV
+    np.testing.assert_array_equal(out[0], np.tile(np.asarray(vn[0], np.float32),
+                                                  (rep, 1)))
+
+
+def test_paged_schedule_reads_each_live_page_once():
+    """The grid visits only live blocks, and an input whose page is dead
+    at a step repeats the page it held before, so the pipeline fetches each
+    live page once and no page outside the live ones."""
+    from repro.kernels.paged_attention import schedule
+    page, pps, ppb = 16, 24, 8                 # 128 positions, 8 pages a block
+    bt = jnp.arange(3 * pps, dtype=jnp.int32).reshape(3, pps) + 100
+    cl = jnp.asarray([0, 13 * 16 - 3, 5 * 16], jnp.int32)   # 0, 13, 5 pages
+    steps, lane, blk, pages = schedule(bt, cl, page)
+    steps = int(steps)
+    assert steps == 1 + 2 + 1
+    assert np.asarray(lane)[:steps].tolist() == [0, 1, 1, 2]
+    assert np.asarray(blk)[:steps].tolist() == [0, 0, 1, 0]
+    held = np.asarray(pages).reshape(-1, ppb)[:steps]
+    live = set(np.asarray(bt[1, :13]).tolist()) | set(np.asarray(bt[2, :5]).tolist())
+    assert set(held.ravel().tolist()) <= live
+    fetched = 0
+    for t in range(steps):
+        prev = held[t - 1] if t else [None] * ppb
+        fetched += sum(int(a != b) for a, b in zip(held[t], prev))
+    assert fetched == len(live)
+    # inputs that are never live hold a live page too: no stale page of the
+    # empty lane 0 is fetched even at the pipeline's first step
+    steps, _, _, pages = schedule(bt, jnp.asarray([0, 9, 7], jnp.int32), page)
+    held = set(np.asarray(pages).reshape(-1, ppb)[:int(steps)].ravel().tolist())
+    assert held == {int(bt[1, 0]), int(bt[2, 0])}
 
 
 @pytest.mark.parametrize("n,k", [(16, 1), (64, 5), (128, 16)])

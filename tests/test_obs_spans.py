@@ -96,6 +96,9 @@ def test_span_names_args_and_clock_stat(traced):
     assert {s[3]["prompt_len"] for s in spans if s[2] == "engine.prefill"} \
         == {3, 5}
     assert {s[3]["rid"] for s in spans if s[2] == "engine.step"} == {0}
+    # this host has no TPU: every decode step gathered
+    assert {s[3]["attn"] for s in spans if s[2] == "engine.decode"} \
+        == {"gather"}
     assert all(s[3]["generation"] in (0, 1, 2)
                for s in spans if s[2] == "host.gc")
 

@@ -409,3 +409,58 @@ def test_engine_device_admission_matches_host(dense_model):
                 "ring path never exercised"
             assert eng.ring_pending == 0
     assert outs[True] == outs[False]
+
+
+def _serve_through_fabric(cfg, params, prompts):
+    from repro.fabric import Fabric, FabricConfig
+    config = FabricConfig(arch=cfg.name, smoke=True, max_batch=3, page_size=8,
+                          num_pages=48, kv_window=2, max_seq=64)
+    with Fabric.open(config, params=params, model_cfg=cfg) as fab:
+        uids = fab.submit_many(prompts, max_new_tokens=9)
+        done = fab.drain(max_steps=200)
+        attn = {e._decode_attn for e in fab.engines}
+    return [done[u].output for u in uids], attn
+
+
+@pytest.mark.parametrize("arch", [
+    "phi3_mini",   # MHA, head_dim 16: each layer's pool slice
+    "gqa_hd128",   # a group of 4 at head_dim 128: the pool read in place
+])
+def test_decode_kernel_serves_the_gather_paths_tokens(monkeypatch, arch):
+    """The paged decode kernel (interpret mode here) serves token for token
+    what the whole-table gather serves: prompts that end on, before and
+    after page boundaries, lanes refilled as requests finish."""
+    import dataclasses
+
+    from repro.serving import paged_model
+    cfg = get_config("phi3_mini", smoke=True)
+    if arch == "gqa_hd128":
+        cfg = dataclasses.replace(cfg, name="gqa-hd128-smoke", num_heads=8,
+                                  num_kv_heads=2, head_dim=128)
+    params = init_params(cfg, KEY)
+    prompts = [[5, 17, 200, 3, 9, 9, 42, 1], [9, 9, 42], [100, 2, 7, 7, 1],
+               [11] * 15, [3, 1, 4, 1, 5, 9, 2, 6, 5]]
+    gathered, attn = _serve_through_fabric(cfg, params, prompts)
+    assert attn == {"gather"}
+    monkeypatch.setattr(paged_model, "kernel_attention",
+                        lambda S, c: S == 1 and c.attn_softcap == 0.0)
+    served, attn = _serve_through_fabric(cfg, params, prompts)
+    assert attn == {"kernel"}
+    assert served == gathered
+    assert len({tuple(o) for o in served}) == len(prompts)
+
+
+def test_decode_kernel_dispatch_follows_the_call_shape(monkeypatch):
+    """Only a decode call (one token per lane) without a logit softcap takes
+    the kernel, and only on a TPU; prefill and softcapped models gather."""
+    import dataclasses
+
+    from repro.kernels import ops
+    from repro.serving import paged_model
+    cfg = get_config("yi_6b", smoke=True)
+    assert not paged_model.kernel_attention(1, cfg)        # this host: CPU
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert paged_model.kernel_attention(1, cfg)
+    assert not paged_model.kernel_attention(7, cfg)        # prefill
+    assert not paged_model.kernel_attention(
+        1, dataclasses.replace(cfg, attn_softcap=30.0))
