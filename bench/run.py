@@ -15,7 +15,9 @@ JSON line as the last line of standard output.
 
 It exits non-zero, printing no result, when JAX's first device is not a
 TPU listed in ``bench/peaks.json``, when there are fewer chips than the
-cell asks for, or when the serving program (``src/``) is missing.
+cell asks for, when the serving program (``src/``) is missing, or when the
+program's configuration or parameter layout differs from the file's, as
+the file's layout (``bench/layouts``) reads it.
 """
 
 from __future__ import annotations
@@ -39,7 +41,7 @@ ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import check, generator, loop, spec  # noqa: E402
+from bench import check, generator, layouts, loop, spec  # noqa: E402
 
 #: after the window (and the profiler's stop), late requests are stepped
 #: on for this long at most
@@ -123,28 +125,23 @@ def memory_peak(devs) -> int:
 
 def check_program(cfg: dict, model_cfg, weights) -> None:
     """Refuse to serve when the program's configuration or parameter layout
-    differs from the configuration file's."""
+    differs from the configuration file's, as its layout module reads it."""
     import jax
 
     from repro.models import init_params
 
-    want = {"d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_key_value_heads"],
-            "num_layers": cfg["num_hidden_layers"], "vocab_size": cfg["vocab_size"],
-            "resolved_head_dim": cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"],
-            "rope_theta": cfg["rope_theta"], "dtype": cfg["torch_dtype"],
-            "tie_embeddings": cfg["tie_word_embeddings"], "block_pattern": ("dense",),
-            "norm": "rmsnorm", "act": cfg["hidden_act"]}
-    got = {k: getattr(model_cfg, k) for k in want}
+    layout = layouts.name(cfg)
+    want = layouts.load(cfg).program_fields(cfg)
+    got = {k: getattr(model_cfg, k, "(no such attribute)") for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
-        raise Refused(f"the program's {model_cfg.name} differs from {cfg['name']}.json: {bad}")
+        raise Refused(f"the program's {model_cfg.name} differs from {cfg['name']}.json "
+                      f"under layout {layout!r}: {bad}")
     theirs = jax.eval_shape(init_params, model_cfg, jax.random.PRNGKey(0))
     shape = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), theirs)
     ours = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), weights)
     if shape != ours:
-        raise Refused("the program's parameter layout differs from bench/weights.py")
+        raise Refused(f"the program's parameter layout differs from bench/layouts/{layout}.py")
 
 
 def open_fabric(cfg: dict, weights, trace: bool, shadow: bool = False):
@@ -296,7 +293,11 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True) -> int:
         return 2
     cover(cfg, traffic)
     phase("shape cover")
-    rec, weights = serve(cfg, traffic, args.seed, args.seconds, bool(args.trace), peaks, dev)
+    try:
+        rec, weights = serve(cfg, traffic, args.seed, args.seconds, bool(args.trace), peaks, dev)
+    except Refused as e:
+        log(f"refused: {e}")
+        return 2
     log(f"window {args.seconds}s: {len(rec['steps'])} steps, "
         f"{sum(1 for t in rec['tracks'] if t.done is not None)} requests done, "
         f"{rec['compiles_in_window']} programs compiled or loaded inside the window")

@@ -1,7 +1,8 @@
 """The control of ``max_logit_gap`` at a size a test run holds: the plain
 reference rounded to int8 or to fp8 (the control of the chip's cells), put
 in the program's place at each served position, reads above the limit on
-every seed, while the program (float32 at this size) reads under it."""
+every seed, while the program (float32 at this size) reads under it; on the
+dense and on the MoE tiny cell."""
 
 import json
 import sys
@@ -14,12 +15,13 @@ from bench.tests import tiny
 LIMIT = 1e-4  # the tiny cells' limit (tiny.make_root)
 
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
+def serve_seeds(tmp_path_factory, cell):
+    """Seeds 1, 2 and 3 of ``cell`` served: (cfg, traffic, run record,
+    weights) per seed."""
     root = tiny.make_root(tmp_path_factory.mktemp("checkout"), limit=LIMIT)
     if str(tiny.REPO / "src") not in sys.path:
         sys.path.insert(0, str(tiny.REPO / "src"))
-    _, _, cfg, traffic, _ = run.spec.load_cell(root, "tiny.open")
+    _, _, cfg, traffic, _ = run.spec.load_cell(root, cell)
     run.count_compiles()
     dev = run.device_info(1, {}, require_tpu=False)
     run.cover(cfg, traffic)
@@ -28,6 +30,16 @@ def served(tmp_path_factory):
         rec, weights = run.serve(cfg, traffic, seed, 2.0, False, {"devices": {}}, dict(dev))
         out[seed] = (cfg, traffic, rec, weights)
     return out
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    return serve_seeds(tmp_path_factory, "tiny.open")
+
+
+@pytest.fixture(scope="module")
+def served_moe(tmp_path_factory):
+    return serve_seeds(tmp_path_factory, "tiny.moe.open")
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -47,6 +59,18 @@ def test_fp8_control_fails_where_the_program_passes(served, seed):
     length = check.padded_length(traffic)
     program = max(check.widest_gaps(cfg, weights, sample, length))
     control = max(check.widest_gaps(cfg, weights, sample, length, "fp8"))
+    assert program <= LIMIT < control
+
+
+@pytest.mark.parametrize("quant", ["int8", "fp8"])
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_moe_control_fails_where_the_program_passes(served_moe, seed, quant):
+    """The same on the MoE cell: its reference rounded, router included."""
+    cfg, traffic, rec, weights = served_moe[seed]
+    sample = [t for t in rec["tracks"] if t.output]
+    length = check.padded_length(traffic)
+    program = max(check.widest_gaps(cfg, weights, sample, length))
+    control = max(check.widest_gaps(cfg, weights, sample, length, quant))
     assert program <= LIMIT < control
 
 

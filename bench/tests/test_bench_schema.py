@@ -115,3 +115,16 @@ def test_layers_are_named_alike():
     for m in BENCH["per_layer"]:
         layers.setdefault(m["layer"].split(" (")[0], set()).add(m["layer"])
     assert all(len(v) == 1 for v in layers.values())
+
+
+@pytest.mark.parametrize("file", [c["file"] for c in BENCH["configs"]]
+                         + sorted(f"bench/tests/data/{p.name}"
+                                  for p in (ROOT / "bench" / "tests" / "data").glob("tiny_*.json")
+                                  if "arch" in json.loads(p.read_text())))
+def test_every_configuration_names_a_layout_that_exports_the_harness_names(file):
+    cfg = json.loads((ROOT / file).read_text())
+    module = importlib.import_module(f"bench.layouts.{cfg.get('layout', 'dense')}")
+    for name in ("shapes", "program_fields", "matmul_params", "weight_bytes",
+                 "kv_bytes_per_token", "decode_flops", "decode_bytes", "prefill_flops",
+                 "prefill_bytes"):
+        assert callable(getattr(module, name)), (file, name)

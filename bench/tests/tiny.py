@@ -10,11 +10,17 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
 
+#: (cell prefix, configuration) of the tiny cells; each gets an open and a
+#: closed loop
+CONFIGS = (("tiny", "tiny_yi"), ("tiny.moe", "tiny_moe"))
+CELLS = [f"{prefix}.{loop}" for prefix, _ in CONFIGS for loop in ("open", "closed")]
+
 
 def make_root(tmp: Path, limit: float = 1.0) -> Path:
     """Cells ``tiny.open`` and ``tiny.closed`` on the program's yi-6b smoke
-    model, with every metric of the real ``BENCHMARK.json`` reported in
-    both and the served-token limit ``limit``."""
+    model and ``tiny.moe.open`` and ``tiny.moe.closed`` on its granite-moe
+    smoke model, with every metric of the real ``BENCHMARK.json`` reported
+    in each and the served-token limit ``limit``."""
     (tmp / "src").symlink_to(REPO / "src")
     b = tmp / "bench"
     for sub in ("traffic", "limits"):
@@ -22,7 +28,8 @@ def make_root(tmp: Path, limit: float = 1.0) -> Path:
     shutil.copy(REPO / "bench" / "peaks.json", b / "peaks.json")
     for loop in ("open", "closed"):
         shutil.copy(DATA / f"tiny_{loop}.json", b / "traffic" / f"tiny_{loop}.json")
-        (b / "limits" / f"tiny.{loop}.json").write_text(json.dumps({"max_logit_gap": limit}))
+    for cell in CELLS:
+        (b / "limits" / f"{cell}.json").write_text(json.dumps({"max_logit_gap": limit}))
     real = json.loads((REPO / "BENCHMARK.json").read_text())
 
     def everywhere(metrics):
@@ -30,10 +37,11 @@ def make_root(tmp: Path, limit: float = 1.0) -> Path:
 
     (tmp / "BENCHMARK.json").write_text(json.dumps({
         **real,
-        "configs": [{"name": "tiny_yi", "source": "tests", "file": str(DATA / "tiny_yi.json"),
-                     "reduced": [], "why": "tests"}],
-        "workloads": [{"name": f"tiny.{loop}", "config": "tiny_yi", "traffic": f"tiny_{loop}",
-                       "chips": 1, "why": "tests"} for loop in ("open", "closed")],
+        "configs": [{"name": name, "source": "tests", "file": str(DATA / f"{name}.json"),
+                     "reduced": [], "why": "tests"} for _, name in CONFIGS],
+        "workloads": [{"name": f"{prefix}.{loop}", "config": name, "traffic": f"tiny_{loop}",
+                       "chips": 1, "why": "tests"}
+                      for prefix, name in CONFIGS for loop in ("open", "closed")],
         "end_to_end": everywhere(real["end_to_end"]),
         "per_layer": everywhere(real["per_layer"]),
     }))

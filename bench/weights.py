@@ -1,11 +1,12 @@
 """The benchmark's weights: random, from the run's seed, made on the device.
 
 One jitted call builds every leaf in the type it is served in, in the
-layout the serving program takes (``params["blocks"]["0"]`` stacked over
-layers, query head h reading key/value head h % num_kv_heads). The
-reference (``configs/dense_reference.py``) reads the same arrays; nothing
-here comes from the program. ``run.py`` checks this layout against the
-program's own before it serves.
+layout the serving program takes, which the configuration's layout module
+describes (``bench/layouts``; ``dense``: ``params["blocks"]["0"]`` stacked
+over layers, query head h reading key/value head h % num_kv_heads). The
+configuration's reference (``configs/<reference>.py``) reads the same
+arrays; nothing here comes from the program. ``run.py`` checks this layout
+against the program's own before it serves.
 """
 
 from __future__ import annotations
@@ -15,7 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from bench import counts
+from bench import layouts
 
 
 def _key(seed: int) -> jax.Array:
@@ -25,33 +26,26 @@ def _key(seed: int) -> jax.Array:
 
 
 def shapes(cfg: dict) -> dict:
-    m = counts.dims(cfg)
-    d, f, h, kv, hd, n, v = (m["d"], m["f"], m["h"], m["kv"], m["hd"],
-                             m["layers"], m["vocab"])
-    return {
-        "embed": (v, d),
-        "final_norm": {"scale": (d,)},
-        "lm_head": (d, v),
-        "blocks": {"0": {
-            "ln1": {"scale": (n, d)},
-            "attn": {"wq": (n, d, h * hd), "wk": (n, d, kv * hd),
-                     "wv": (n, d, kv * hd), "wo": (n, h * hd, d)},
-            "ln2": {"scale": (n, d)},
-            "mlp": {"wg": (n, d, f), "wu": (n, d, f), "wd": (n, f, d)},
-        }},
-    }
+    """The parameter tree of ``cfg``'s layout (``bench/layouts``)."""
+    return layouts.load(cfg).shapes(cfg)
 
 
-def _flat(tree):
+def _flat(tree, dtype: str):
+    """(path, shape, dtype) of every leaf, and the tree's structure; a leaf
+    is a shape, or a ``(shape, dtype)`` pair."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         tree, is_leaf=lambda x: isinstance(x, tuple))
-    return [(jax.tree_util.keystr(p), s) for p, s in flat], treedef
+    leaves = []
+    for p, s in flat:
+        shape, dt = s if len(s) == 2 and isinstance(s[1], str) else (s, dtype)
+        leaves.append((jax.tree_util.keystr(p), tuple(shape), dt))
+    return leaves, treedef
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _init(leaves, dtype: str, std: float, key):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _init(leaves, std: float, key):
     out = []
-    for i, (path, shape) in enumerate(leaves):
+    for i, (path, shape, dtype) in enumerate(leaves):
         if "scale" in path:
             out.append(jnp.ones(shape, dtype))
         else:
@@ -62,8 +56,7 @@ def _init(leaves, dtype: str, std: float, key):
 
 def make(cfg: dict, seed: int):
     """Every weight of ``cfg``, N(0, initializer_range) in the served
-    dtype, RMSNorm scales 1, from ``seed``."""
-    leaves, treedef = _flat(shapes(cfg))
-    arrays = _init(tuple(leaves), cfg["torch_dtype"],
-                   float(cfg["initializer_range"]), _key(seed))
+    dtype (or the leaf's own), RMSNorm scales 1, from ``seed``."""
+    leaves, treedef = _flat(shapes(cfg), cfg["torch_dtype"])
+    arrays = _init(tuple(leaves), float(cfg["initializer_range"]), _key(seed))
     return jax.tree_util.tree_unflatten(treedef, arrays)
