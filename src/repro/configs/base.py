@@ -23,19 +23,42 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: Optional[int] = None
-    # block structure: pattern repeated num_layers/len(pattern) times
+    # block structure: ``first_dense_layers`` leading dense layers, then the
+    # pattern repeated (num_layers - first_dense_layers)/len(pattern) times
     block_pattern: Tuple[str, ...] = ("dense",)
-    # MoE
+    first_dense_layers: int = 0
+    # MoE. The router scores num_experts; this chip holds experts_held of
+    # them (0 = all), from expert_offset on: one rank of expert parallelism
     num_experts: int = 0
     num_experts_per_tok: int = 0
     expert_d_ff: int = 0
     capacity_factor: float = 1.25
+    experts_held: int = 0
+    expert_offset: int = 0
+    # dropless: every claim on a held expert is computed (grouped matmul);
+    # otherwise claims over capacity_factor are dropped (assign_slots)
+    moe_dropless: bool = False
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    shared_expert_d_ff: int = 0      # one gated MLP every token runs; 0 = none
+    # latent attention (MLA) when kv_lora_rank > 0: a token keeps
+    # kv_lora_rank + qk_rope_head_dim values per layer
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     # attention details
     rope_theta: float = 10000.0
+    # YaRN RoPE scaling (arXiv:2309.00071) when rope_factor > 1
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     sliding_window: int = 0          # 0 = full attention
     attn_softcap: float = 0.0
     # misc
@@ -84,10 +107,19 @@ class ModelConfig:
 
     @property
     def pattern_repeats(self) -> int:
-        assert self.num_layers % len(self.block_pattern) == 0, (
-            f"{self.name}: num_layers {self.num_layers} not divisible by "
-            f"pattern {self.block_pattern}")
-        return self.num_layers // len(self.block_pattern)
+        n = self.num_layers - self.first_dense_layers
+        assert n % len(self.block_pattern) == 0, (
+            f"{self.name}: {n} layers after {self.first_dense_layers} leading "
+            f"dense ones not divisible by pattern {self.block_pattern}")
+        return n // len(self.block_pattern)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def is_subquadratic(self) -> bool:
@@ -114,6 +146,7 @@ SHAPES = {
 ARCHS = [
     "glm4_9b", "yi_6b", "phi3_mini", "command_r_35b", "llama4_maverick",
     "granite_moe", "xlstm_125m", "hymba_1_5b", "llava_next", "musicgen_large",
+    "deepseek_v2_lite",
 ]
 
 # canonical ids as given in the assignment -> module names
@@ -128,6 +161,7 @@ _ALIASES = {
     "hymba-1.5b": "hymba_1_5b",
     "llava-next-mistral-7b": "llava_next",
     "musicgen-large": "musicgen_large",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -46,6 +46,17 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, cached_lens, sched,
                                interpret=not on_tpu())
 
 
+def latent_attention(q, c_new, r_new, c_pages, r_pages, cached_lens, sched,
+                     layer=0, *, sm_scale):
+    """Absorbed MLA decode of each lane's new token over its cached latent
+    pages and itself: q [B, H, dc + dr] (q̃ then q_rope); c/r_new [B, dc],
+    [B, dr]; pages [L, P, 1, page, dc] and [.., dr] -> the weighted latent
+    [B, H, dc]."""
+    return _pa.latent_attention(q, c_new, r_new, c_pages, r_pages,
+                                cached_lens, sched, layer, sm_scale=sm_scale,
+                                interpret=not on_tpu())
+
+
 paged_schedule = _pa.schedule
 
 

@@ -28,6 +28,11 @@ Layouts: q [B, H, hd]; k/v_new [B, KV, hd]; k/v pages [L, P, KV, page, hd],
 read at ``layer``; block_tables [B, pages_per_seq] int32 (entries past a
 lane's live pages are never read); cached_lens [B] int32, the positions each
 lane holds before this token.
+
+Latent heads (MLA, ``latent_attention``): the same kernel over a pool of
+one head whose k stream holds the normed latent ``c`` and whose v stream
+holds the rotary key; every query head of a lane is one row of the group,
+its query ``[q̃ | q_rope]``, and the values are the k stream's pages.
 """
 
 from __future__ import annotations
@@ -88,7 +93,8 @@ def schedule(block_tables, cached_lens, page: int) -> Schedule:
 
 
 def _paged_kernel(layer_ref, lane_ref, blk_ref, pages_ref, cl_ref, q_ref,
-                  kn_ref, vn_ref, *refs, ppb: int, page: int, sm_scale: float):
+                  kn_ref, vn_ref, *refs, ppb: int, page: int, sm_scale: float,
+                  latent: bool = False):
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * ppb:]
     t = pl.program_id(0)
@@ -117,22 +123,104 @@ def _paged_kernel(layer_ref, lane_ref, blk_ref, pages_ref, cl_ref, q_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
+    if latent:
+        # one latent head: the k stream holds c (the values too), the v
+        # stream the rotary key; the query is [q̃ | q_rope]
+        dc = kn_ref.shape[-1]
+        q_c, q_r = q[..., :dc], q[..., dc:]
+
+        def scores(k, v):
+            return (jnp.einsum("grd,gtd->grt", q_c, k,
+                               preferred_element_type=jnp.float32)
+                    + jnp.einsum("grd,gtd->grt", q_r, v,
+                                 preferred_element_type=jnp.float32))
+
+        def new_scores(kn):
+            rn = vn_ref[...]
+            return (jnp.sum(q_c.astype(jnp.float32) * kn.astype(jnp.float32),
+                            axis=-1, keepdims=True)
+                    + jnp.sum(q_r.astype(jnp.float32) * rn.astype(jnp.float32),
+                              axis=-1, keepdims=True))
+
+        def values(k, v):
+            return k
+    else:
+        def scores(k, v):
+            return jnp.einsum("grd,gtd->grt", q, k,
+                              preferred_element_type=jnp.float32)
+
+        def new_scores(kn):
+            return jnp.sum(q.astype(jnp.float32) * kn.astype(jnp.float32),
+                           axis=-1, keepdims=True)
+
+        def values(k, v):
+            return v
+
     @pl.when(blk * bk < cached)
     def _pages():
         k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [KV, bk, hd]
         v = jnp.concatenate([r[...] for r in v_refs], axis=1)
-        s = jnp.einsum("grd,gtd->grt", q, k,
-                       preferred_element_type=jnp.float32) * sm_scale
+        s = scores(k, v) * sm_scale
         pos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        online(jnp.where(pos < cached, s, NEG_INF), v)
+        online(jnp.where(pos < cached, s, NEG_INF), values(k, v))
 
     @pl.when((blk + 1) * bk >= cached)
     def _new_token():
         kn = kn_ref[...]                                        # [KV, 1, hd]
-        s = jnp.sum(q.astype(jnp.float32) * kn.astype(jnp.float32), axis=-1,
-                    keepdims=True) * sm_scale                   # [KV, rep, 1]
-        online(s, vn_ref[...])
+        s = new_scores(kn) * sm_scale                           # [KV, rep, 1]
+        online(s, kn if latent else vn_ref[...])
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _call(q, k_new, v_new, k_pages, v_pages, cached_lens, sched, layer, *,
+          latent: bool, sm_scale: float, name: str, interpret: bool):
+    """The kernel over grid ``sched``: q [B, KV, rep, dq], k/v_new
+    [B, KV, 1, dk/dv], pages [L, P, KV, page, dk/dv] -> [B, KV, rep, do]
+    (``do`` is the k stream's width for a latent head, else the v's)."""
+    B, KV, rep, dq = q.shape
+    page = k_pages.shape[3]
+    dk, dv = k_pages.shape[-1], v_pages.shape[-1]
+    do = dk if latent else dv
+    ppb = sched.pages.shape[0] // sched.lane.shape[0]
+
+    def lane_block(rows, width):
+        return pl.BlockSpec((None, KV, rows, width),
+                            lambda t, ly, ln, bl, pg, cl: (ln[t], 0, 0, 0))
+
+    def page_block(j, width):
+        return pl.BlockSpec(
+            (None, None, KV, page, width),
+            lambda t, ly, ln, bl, pg, cl: (ly[0], pg[t * ppb + j], 0, 0, 0))
+
+    if not interpret:
+        # The pool stays in HBM: unconstrained, XLA may stage the whole pool
+        # in VMEM before the call, a copy of every page, live or not.
+        k_pages = pltpu.with_memory_space_constraint(k_pages, pltpu.HBM)
+        v_pages = pltpu.with_memory_space_constraint(v_pages, pltpu.HBM)
+    kernel = functools.partial(_paged_kernel, ppb=ppb, page=page,
+                               sm_scale=sm_scale, latent=latent)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(sched.steps,),
+        in_specs=([lane_block(rep, dq), lane_block(1, dk), lane_block(1, dv)]
+                  + [page_block(j, dk) for j in range(ppb)]
+                  + [page_block(j, dv) for j in range(ppb)]),
+        out_specs=lane_block(rep, do),
+        scratch_shapes=[pltpu.VMEM((KV, rep, 1), jnp.float32),
+                        pltpu.VMEM((KV, rep, 1), jnp.float32),
+                        pltpu.VMEM((KV, rep, do), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, rep, do), q.dtype),
+        name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), sched.lane, sched.blk,
+      sched.pages, cached_lens, q, k_new, v_new, *([k_pages] * ppb),
+      *([v_pages] * ppb))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -152,49 +240,42 @@ def paged_attention(
     ``cached_lens[b]`` positions its block table holds in layer ``layer`` of
     the pool, and over itself. Returns [B, H, hd]."""
     B, H, hd = q.shape
-    KV, page = k_pages.shape[2:4]
+    KV = k_pages.shape[2]
     rep = H // KV
-    ppb = sched.pages.shape[0] // sched.lane.shape[0]
     # r-major groups: head h = r * KV + g reads KV head g
     qg = q.reshape(B, rep, KV, hd).transpose(0, 2, 1, 3)       # [B, KV, rep, hd]
     kn = k_new.astype(k_pages.dtype)[:, :, None]                # [B, KV, 1, hd]
     vn = v_new.astype(v_pages.dtype)[:, :, None]
-
-    def lane_block(rows):
-        return pl.BlockSpec((None, KV, rows, hd),
-                            lambda t, ly, ln, bl, pg, cl: (ln[t], 0, 0, 0))
-
-    def page_block(j):
-        return pl.BlockSpec(
-            (None, None, KV, page, hd),
-            lambda t, ly, ln, bl, pg, cl: (ly[0], pg[t * ppb + j], 0, 0, 0))
-
-    if not interpret:
-        # The pool stays in HBM: unconstrained, XLA may stage the whole pool
-        # in VMEM before the call, a copy of every page, live or not.
-        k_pages = pltpu.with_memory_space_constraint(k_pages, pltpu.HBM)
-        v_pages = pltpu.with_memory_space_constraint(v_pages, pltpu.HBM)
-    kernel = functools.partial(_paged_kernel, ppb=ppb, page=page,
-                               sm_scale=1.0 / (hd ** 0.5))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(sched.steps,),
-        in_specs=([lane_block(rep), lane_block(1), lane_block(1)]
-                  + [page_block(j) for j in range(ppb)] * 2),
-        out_specs=lane_block(rep),
-        scratch_shapes=[pltpu.VMEM((KV, rep, 1), jnp.float32),
-                        pltpu.VMEM((KV, rep, 1), jnp.float32),
-                        pltpu.VMEM((KV, rep, hd), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), q.dtype),
-        name="paged_decode_attention",
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), sched.lane, sched.blk,
-      sched.pages, cached_lens, qg, kn, vn, *([k_pages] * ppb),
-      *([v_pages] * ppb))
+    out = _call(qg, kn, vn, k_pages, v_pages, cached_lens, sched, layer,
+                latent=False, sm_scale=1.0 / (hd ** 0.5),
+                name="paged_decode_attention", interpret=interpret)
     return out.transpose(0, 2, 1, 3).reshape(B, H, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def latent_attention(
+    q: jax.Array,             # [B, H, dc + dr]: q̃ then q_rope
+    c_new: jax.Array,         # [B, dc]
+    r_new: jax.Array,         # [B, dr]
+    c_pages: jax.Array,       # [L, P, 1, page, dc]
+    r_pages: jax.Array,       # [L, P, 1, page, dr]
+    cached_lens: jax.Array,   # [B] int32
+    sched: Schedule,
+    layer: jax.Array | int = 0,
+    *,
+    sm_scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Absorbed latent attention (MLA decode): every query head of a lane
+    reads the lane's one latent head, keys ``[c | k_rope]`` and values
+    ``c``, from the same pages and schedule as ``paged_attention``. The
+    score is ``q̃·c + q_rope·k_rope``; returns the weighted latent
+    [B, H, dc]."""
+    B, H, _ = q.shape
+    out = _call(q[:, None].astype(c_pages.dtype),
+                c_new.astype(c_pages.dtype)[:, None, None],
+                r_new.astype(r_pages.dtype)[:, None, None],
+                c_pages, r_pages, cached_lens, sched, layer, latent=True,
+                sm_scale=sm_scale, name="mla_decode_attention",
+                interpret=interpret)
+    return out[:, 0]

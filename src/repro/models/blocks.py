@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as M
 from repro.models import ssm as S
 
@@ -42,6 +43,8 @@ def _dense_init(key, shape, dtype, scale=0.02):
 
 
 def _init_attn(cfg: ModelConfig, key) -> dict:
+    if cfg.is_mla:
+        return _init_mla(cfg, key)
     D, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     out_scale = 0.02 / max(1.0, (2 * cfg.num_layers) ** 0.5)
@@ -53,8 +56,38 @@ def _init_attn(cfg: ModelConfig, key) -> dict:
     }
 
 
-def _init_mlp(cfg: ModelConfig, key) -> dict:
-    D, F = cfg.d_model, cfg.d_ff
+def _init_mla(cfg: ModelConfig, key) -> dict:
+    """Latent attention's weights (``models/mla.py``)."""
+    D, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    out_scale = 0.02 / max(1.0, (2 * cfg.num_layers) ** 0.5)
+    return {
+        "wq": _dense_init(k1, (D, H * (dn + dr)), _dt(cfg)),
+        "wkv_a": _dense_init(k2, (D, r + dr), _dt(cfg)),
+        "kv_norm": {"scale": jnp.ones((r,), _dt(cfg))},
+        "wkv_b": _dense_init(k3, (r, H * (dn + dv)), _dt(cfg)),
+        "wo": _dense_init(k4, (H * dv, D), _dt(cfg), out_scale),
+    }
+
+
+def attention(x, p, cfg: ModelConfig, positions=None, cache=None):
+    """The block's attention on normed ``x``: latent (MLA) or GQA."""
+    if cfg.is_mla:
+        return mla.mla_block(x, p, cfg, positions, cache)
+    return L.attention_block(
+        x, p,
+        num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+        head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+        sliding_window=cfg.sliding_window, softcap=cfg.attn_softcap,
+        positions=positions, cache=cache, impl=cfg.attention_impl,
+        chunk_kv=cfg.attn_chunk_kv, attn_unroll=cfg.attn_scan_unroll,
+        kv_block_axis=cfg.kv_block_axis, batch_axes=cfg.batch_axes,
+    )
+
+
+def _init_mlp(cfg: ModelConfig, key, width: int = 0) -> dict:
+    D, F = cfg.d_model, width or cfg.d_ff
     k1, k2, k3 = jax.random.split(key, 3)
     out_scale = 0.02 / max(1.0, (2 * cfg.num_layers) ** 0.5)
     return {
@@ -80,15 +113,8 @@ def init_dense(cfg: ModelConfig, key) -> dict:
 
 
 def apply_dense(x, p, cfg: ModelConfig, positions=None, cache=None):
-    h, new_cache = L.attention_block(
-        L.norm(x, p["ln1"], cfg.norm), p["attn"],
-        num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-        sliding_window=cfg.sliding_window, softcap=cfg.attn_softcap,
-        positions=positions, cache=cache, impl=cfg.attention_impl,
-        chunk_kv=cfg.attn_chunk_kv, attn_unroll=cfg.attn_scan_unroll,
-        kv_block_axis=cfg.kv_block_axis, batch_axes=cfg.batch_axes,
-    )
+    h, new_cache = attention(L.norm(x, p["ln1"], cfg.norm), p["attn"], cfg,
+                             positions, cache)
     x = x + h
     x = x + L.swiglu(L.norm(x, p["ln2"], cfg.norm), p["mlp"], cfg.act)
     return x, jnp.zeros((), jnp.float32), new_cache
@@ -100,39 +126,52 @@ def apply_dense(x, p, cfg: ModelConfig, positions=None, cache=None):
 
 
 def init_moe(cfg: ModelConfig, key) -> dict:
+    """The router scores ``num_experts``; the layer holds ``held_experts``
+    of them (and a shared expert where ``shared_expert_d_ff``)."""
     D, E, F = cfg.d_model, cfg.num_experts, cfg.expert_d_ff or cfg.d_ff
+    G = cfg.held_experts
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     out_scale = 0.02 / max(1.0, (2 * cfg.num_layers) ** 0.5)
+    moe = {
+        "router": _dense_init(k2, (D, E), jnp.float32),
+        "wg": _dense_init(k3, (G, D, F), _dt(cfg)),
+        "wu": _dense_init(k4, (G, D, F), _dt(cfg)),
+        "wd": _dense_init(k5, (G, F, D), _dt(cfg), out_scale),
+    }
+    if cfg.shared_expert_d_ff:
+        moe["shared"] = _init_mlp(cfg, jax.random.fold_in(key, 6),
+                                  cfg.shared_expert_d_ff)
     return {
         "ln1": _norm_params(cfg, D),
         "attn": _init_attn(cfg, k1),
         "ln2": _norm_params(cfg, D),
-        "moe": {
-            "router": _dense_init(k2, (D, E), jnp.float32),
-            "wg": _dense_init(k3, (E, D, F), _dt(cfg)),
-            "wu": _dense_init(k4, (E, D, F), _dt(cfg)),
-            "wd": _dense_init(k5, (E, F, D), _dt(cfg), out_scale),
-        },
+        "moe": moe,
     }
 
 
-def apply_moe(x, p, cfg: ModelConfig, positions=None, cache=None):
-    h, new_cache = L.attention_block(
-        L.norm(x, p["ln1"], cfg.norm), p["attn"],
-        num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
-        sliding_window=cfg.sliding_window, softcap=cfg.attn_softcap,
-        positions=positions, cache=cache, impl=cfg.attention_impl,
-        chunk_kv=cfg.attn_chunk_kv, attn_unroll=cfg.attn_scan_unroll,
-        kv_block_axis=cfg.kv_block_axis, batch_axes=cfg.batch_axes,
-    )
-    x = x + h
+def expert_layer(x, p, cfg: ModelConfig):
+    """The routed MLP on normed ``x`` -> (y, aux, claims on held experts):
+    dropless over the held experts where ``moe_dropless``, else by capacity
+    over all of them (claims not counted: None)."""
+    if cfg.moe_dropless:
+        return M.held_moe_block(
+            x, p, num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
+            expert_offset=cfg.expert_offset,
+            norm_topk_prob=cfg.norm_topk_prob, act=cfg.act)
     y, aux = M.moe_block(
-        L.norm(x, p["ln2"], cfg.norm), p["moe"],
-        num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
+        x, p, num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
         capacity_factor=cfg.capacity_factor, act=cfg.act,
         groups=cfg.moe_groups,
     )
+    return y, aux, None
+
+
+def apply_moe(x, p, cfg: ModelConfig, positions=None, cache=None):
+    h, new_cache = attention(L.norm(x, p["ln1"], cfg.norm), p["attn"], cfg,
+                             positions, cache)
+    x = x + h
+    with jax.named_scope("moe_experts"):
+        y, aux, _ = expert_layer(L.norm(x, p["ln2"], cfg.norm), p["moe"], cfg)
     return x + y, aux, new_cache
 
 
@@ -268,6 +307,8 @@ def cache_len(cfg: ModelConfig, seq_len: int) -> int:
 
 def init_cache_kind(kind: str, cfg: ModelConfig, batch: int, seq_len: int):
     dt = jnp.dtype(cfg.dtype)
+    if kind in ("dense", "moe") and cfg.is_mla:
+        return mla.make_cache(cfg, batch, cache_len(cfg, seq_len), dt)
     if kind in ("dense", "moe"):
         return L.make_kv_cache(batch, cache_len(cfg, seq_len), cfg.num_kv_heads,
                                cfg.resolved_head_dim, dt)

@@ -13,6 +13,7 @@ the window is reclaimed by the next insert, coordination-free (DESIGN.md §4).
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -55,12 +56,56 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, num_heads, head_dim]; positions: [B, S]."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor 0.1 * mscale * ln(factor) + 1 (1 unscaled)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_max_pos: int,
+               beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN frequencies (arXiv:2309.00071, "NTK-by-parts"): pair j keeps
+    theta^(-2j/dim) below the correction range, is divided by ``factor``
+    above it, and ramps linearly between. The range is where a pair turns
+    ``beta_fast`` to ``beta_slow`` times over ``original_max_pos``."""
+    def pair(rotations):
+        return (dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(dim, theta)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_tables(cfg, dim: int) -> Tuple[jax.Array, float]:
+    """(frequencies, cos/sin scale) of a ``dim``-wide rotary head under
+    ``cfg``'s RoPE: plain, or YaRN where ``cfg.rope_factor > 1``."""
+    if cfg.rope_factor <= 1:
+        return rope_freqs(dim, cfg.rope_theta), 1.0
+    freqs = yarn_freqs(dim, cfg.rope_theta, cfg.rope_factor,
+                       cfg.rope_original_max_pos, cfg.yarn_beta_fast,
+                       cfg.yarn_beta_slow)
+    scale = (yarn_mscale(cfg.rope_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim))
+    return freqs, scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               scale: float = 1.0) -> jax.Array:
+    """x: [B, S, num_heads, head_dim]; positions: [B, S]. ``freqs`` and
+    ``scale`` (of cos and sin) from :func:`rope_tables` replace the plain
+    ``theta`` frequencies."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # [hd/2]
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)  # [hd/2]
     angles = positions[..., :, None, None].astype(jnp.float32) * freqs
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     x1, x2 = x[..., : hd // 2], x[..., hd // 2 :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

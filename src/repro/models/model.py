@@ -60,6 +60,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
         sub = jax.vmap(lambda k: B.INIT[kind](cfg, jax.random.fold_in(k, j)))(keys)
         blocks[str(j)] = sub
     params["blocks"] = blocks
+    if cfg.first_dense_layers:
+        # leading dense layers, before the pattern, stacked like a block
+        lead = jax.random.split(jax.random.fold_in(key, 1), cfg.first_dense_layers)
+        params["lead"] = jax.vmap(lambda k: B.init_dense(cfg, k))(lead)
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(
             k_head, (cfg.d_model, cfg.vocab_size), jnp.float32) * 0.02).astype(dt)
@@ -71,7 +75,8 @@ def param_count(params) -> int:
 
 
 def active_param_count(cfg: ModelConfig, params) -> int:
-    """Active params per token (MoE: top_k of num_experts routed)."""
+    """Active params per token (MoE: top_k of num_experts routed; of the
+    held experts a token claims top_k / num_experts on average)."""
     total = param_count(params)
     if cfg.num_experts == 0:
         return total
@@ -79,7 +84,8 @@ def active_param_count(cfg: ModelConfig, params) -> int:
     for j, kind in enumerate(cfg.block_pattern):
         if kind == "moe":
             sub = params["blocks"][str(j)]["moe"]
-            expert += sum(x.size for k, x in sub.items() if k != "router")
+            expert += sum(x.size for k, x in sub.items()
+                          if k not in ("router", "shared"))
     active_frac = cfg.num_experts_per_tok / cfg.num_experts
     return int(total - expert + expert * active_frac)
 
@@ -87,6 +93,11 @@ def active_param_count(cfg: ModelConfig, params) -> int:
 # ---------------------------------------------------------------------------
 # forward (train / prefill-style full sequence)
 # ---------------------------------------------------------------------------
+
+
+def lead_layer(params, i: int):
+    """Parameters of leading dense layer ``i`` (``params["lead"]``)."""
+    return jax.tree_util.tree_map(lambda a: a[i], params["lead"])
 
 
 def _logits(x: jax.Array, params, cfg: ModelConfig) -> jax.Array:
@@ -106,6 +117,8 @@ def apply(
     if extra_embeds is not None:
         x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
     x = _shard_batch(x, cfg)
+    for i in range(cfg.first_dense_layers):
+        x, _, _ = B.apply_dense(x, lead_layer(params, i), cfg)
 
     def super_fn(x, layer_p):
         aux = jnp.zeros((), jnp.float32)
@@ -127,17 +140,28 @@ def apply(
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int) -> Dict[str, Any]:
-    """Stacked per-pattern-position caches + shared position counter."""
+    """Stacked per-pattern-position caches (and one per leading dense
+    layer) + shared position counter."""
     r = cfg.pattern_repeats
     blocks = {}
     for j, kind in enumerate(cfg.block_pattern):
         one = B.init_cache_kind(kind, cfg, batch, seq_len)
         blocks[str(j)] = jax.tree_util.tree_map(
             lambda x: jnp.tile(x[None], (r,) + (1,) * x.ndim), one)
-    return {"blocks": blocks, "pos": jnp.zeros((batch,), jnp.int32)}
+    cache = {"blocks": blocks, "pos": jnp.zeros((batch,), jnp.int32)}
+    if cfg.first_dense_layers:
+        cache["lead"] = [B.init_cache_kind("dense", cfg, batch, seq_len)
+                         for _ in range(cfg.first_dense_layers)]
+    return cache
 
 
 def _run_with_cache(params, x, cfg: ModelConfig, cache, positions):
+    lead = []
+    for i in range(cfg.first_dense_layers):
+        x, _, nc = B.apply_dense(x, lead_layer(params, i), cfg,
+                                 positions=positions, cache=cache["lead"][i])
+        lead.append(nc)
+
     def step(x, xs):
         layer_p, layer_c = xs
         new_c = {}
@@ -150,7 +174,14 @@ def _run_with_cache(params, x, cfg: ModelConfig, cache, positions):
     x, new_blocks = jax.lax.scan(step, x, (params["blocks"], cache["blocks"]),
                                  unroll=cfg.scan_unroll)
     x = L.norm(x, params["final_norm"], cfg.norm)
-    return x, new_blocks
+    return x, new_blocks, lead
+
+
+def _new_cache(new_blocks, lead, pos):
+    out = {"blocks": new_blocks, "pos": pos}
+    if lead:
+        out["lead"] = lead
+    return out
 
 
 def prefill(params, tokens: jax.Array, cfg: ModelConfig, cache,
@@ -163,18 +194,18 @@ def prefill(params, tokens: jax.Array, cfg: ModelConfig, cache,
     x = _shard_batch(x, cfg)
     Bsz, S = x.shape[0], x.shape[1]
     positions = cache["pos"][:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    x, new_blocks = _run_with_cache(params, x, cfg, cache, positions)
+    x, new_blocks, lead = _run_with_cache(params, x, cfg, cache, positions)
     logits = _logits(x[:, -1:], params, cfg)[:, 0]
-    return logits, {"blocks": new_blocks, "pos": cache["pos"] + S}
+    return logits, _new_cache(new_blocks, lead, cache["pos"] + S)
 
 
 def decode_step(params, tokens: jax.Array, cfg: ModelConfig, cache):
     """One-token decode. tokens [B, 1] -> (logits [B, V], cache')."""
     x = _shard_batch(params["embed"][tokens], cfg)
     positions = cache["pos"][:, None]
-    x, new_blocks = _run_with_cache(params, x, cfg, cache, positions)
+    x, new_blocks, lead = _run_with_cache(params, x, cfg, cache, positions)
     logits = _logits(x, params, cfg)[:, 0]
-    return logits, {"blocks": new_blocks, "pos": cache["pos"] + 1}
+    return logits, _new_cache(new_blocks, lead, cache["pos"] + 1)
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.domain import window_admit
+from repro.models.layers import swiglu
 
 
 def assign_slots(expert_ids: jax.Array, num_experts: int, capacity: int) -> Tuple[jax.Array, jax.Array]:
@@ -118,3 +119,62 @@ def moe_block(
     aux = E * jnp.sum(me * ce)
 
     return y.reshape(B, S, D).astype(x.dtype), aux
+
+
+def held_moe_block(
+    x: jax.Array,  # [B, S, D]
+    p: dict,       # router [D, E]; wg/wu [G, D, F]; wd [G, F, D]; shared?
+    *,
+    num_experts: int,
+    top_k: int,
+    expert_offset: int = 0,
+    norm_topk_prob: bool = True,
+    act: str = "silu",
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless expert layer of one expert-parallel rank: the router scores
+    all ``num_experts`` (E) experts in float32, softmax over all of them,
+    and each token keeps its top ``top_k``; this rank holds the G experts
+    ``expert_offset .. expert_offset + G - 1`` and computes every claim on
+    them, grouped by expert (``jax.lax.ragged_dot``: each held expert's
+    weights multiply only the rows routed to it). A claim on an expert held
+    elsewhere adds nothing here. The gates are the raw probabilities
+    unless ``norm_topk_prob``. A shared expert (``p["shared"]``), if any,
+    runs on every token.
+
+    Returns (y [B, S, D], aux load-balancing term, claims on held experts).
+    """
+    B, S, D = x.shape
+    T, k = B * S, top_k
+    G = p["wg"].shape[0]
+    xt = x.reshape(T, D)
+
+    logits = jnp.matmul(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)     # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, k)                         # [T, k]
+    if norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    local = ids - expert_offset
+    held = (local >= 0) & (local < G)
+    # held claims sorted by expert (token order within one), the rest last
+    group = jnp.where(held, local, G).reshape(-1)                # [T*k]
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=G + 1)[:G].astype(jnp.int32)
+    xs = xt[order // k]                                          # [T*k, D]
+    g = jax.lax.ragged_dot(xs, p["wg"], sizes)
+    u = jax.lax.ragged_dot(xs, p["wu"], sizes)
+    a = jax.nn.silu(g) if act == "silu" else jax.nn.gelu(g)
+    out = jax.lax.ragged_dot(a * u, p["wd"], sizes)               # [T*k, D]
+    n_held = jnp.sum(sizes)
+    # rows past the held claims belong to no group: zero them explicitly
+    out = jnp.where((jnp.arange(T * k) < n_held)[:, None], out, 0)
+    per_claim = out[jnp.argsort(order)].reshape(T, k, D)
+    w = jnp.where(held, gates, 0.0).astype(per_claim.dtype)
+    y = jnp.einsum("tkd,tk->td", per_claim, w)
+    if "shared" in p:
+        y = y + swiglu(xt, p["shared"], act)
+
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(ids[:, 0], num_experts, dtype=jnp.float32), axis=0)
+    aux = num_experts * jnp.sum(me * ce)
+    return y.reshape(B, S, D).astype(x.dtype), aux, n_held

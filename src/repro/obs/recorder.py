@@ -57,12 +57,14 @@ RESCUE = "rescue"                # reclaim stole stalled-claimer data (Alg 4)
 CLAIM_BLOCK = "claim_block"      # device-ring fused kernel invocation
 FLUSH = "flush"                  # device-ring checkpoint/resize boundary
 CONTROL = "control"              # control-plane decision (resize/weights)
+EXPERT_ROUTE = "expert_route"    # a forward's claims on held experts (arg:
+                                 # (held, dropped); cls: prefill | decode)
 
 LIFECYCLE_STAGES: Tuple[str, ...] = (
     SUBMIT, WINDOW_ADMIT, SHARD_ENQUEUE, DRAIN, SEAT,
     LANE_PREFILL, DECODE, COMPLETE)
 CONTROL_EVENTS: Tuple[str, ...] = (STEAL, REQUEUE, RESCUE, CLAIM_BLOCK,
-                                   FLUSH, CONTROL)
+                                   FLUSH, CONTROL, EXPERT_ROUTE)
 
 #: Span taxonomy (DESIGN.md §13), outermost first. The names are the wire
 #: strings the trace reduction reads; emit sites use the literals.
@@ -76,7 +78,8 @@ SPAN_NAMES: Tuple[str, ...] = (
     "engine.prefill",     # one request laned (arg: prompt_len)
     "engine.grow_pages",  # Engine._grow_pages
     "engine.decode",      # the decode forward and its host reads (arg:
-                          # attn, "kernel" or "gather")
+                          # attn, "kernel" or "gather", "mla_" before
+                          # either for latent attention)
     "engine.bookkeep",    # per-lane loop, completions, retirement
     "host.gc",            # a collector pause (arg: generation)
 )

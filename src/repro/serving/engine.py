@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.obs.recorder import NO_SPAN
+from repro.obs.recorder import EXPERT_ROUTE, NO_SPAN
 from repro.sched import Envelope, QueueClass, ReplicaSet, Scheduler
 from repro.serving import paged_model
 from repro.serving.admission import DeviceAdmissionRing, resolve_device_admission
@@ -85,7 +85,7 @@ class Engine:
                  policy="strict", sched=None, forward_fn=None,
                  device_admission=False, admit_prefetch: int = 0):
         assert all(k in ("dense", "moe") for k in cfg.block_pattern), \
-            "paged engine serves attention-based families"
+            "paged engine serves attention-based families (GQA or MLA)"
         self.cfg, self.params = cfg, params
         self.max_batch, self.page_size, self.max_seq = max_batch, page_size, max_seq
         self.pps = max_seq // page_size
@@ -122,9 +122,9 @@ class Engine:
         # shared callable so N engines share one compilation cache.
         self._forward = forward_fn or make_paged_forward(cfg)
         # which attention the decode step's forward takes (the engine.decode
-        # span's ``attn``): the paged kernel or the whole-table gather
-        self._decode_attn = ("kernel" if paged_model.kernel_attention(1, cfg)
-                             else "gather")
+        # span's ``attn``): the paged kernel or the whole-table gather, MLA's
+        # prefixed ``mla_``
+        self._decode_attn = paged_model.decode_path(cfg)
         # Device-resident admission (DESIGN.md §12): policy-drained batches
         # route through a bounded CMP ring on the accelerator — one fused
         # reclaim+enqueue+claim+publish invocation per step. "auto" enables
@@ -325,10 +325,9 @@ class Engine:
                 toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
                 bt = self.block_tables[lane:lane + 1]
                 sl = jnp.zeros((1,), jnp.int32)
-                logits, self.pool.k_pages, self.pool.v_pages = self._forward(
-                    self.params, toks, self.pool.k_pages, self.pool.v_pages,
-                    bt, sl)
-                tok = int(jnp.argmax(logits[0]))
+                out = self._call_forward(toks, bt, sl)
+                tok = int(jnp.argmax(out[0][0]))
+                self._emit_routes("prefill", out)
                 rec = self._obs
                 if rec is not None and rec.sampled(env.seq):
                     rec.emit("lane_prefill", qc.name, env.seq, arg=lane)
@@ -382,6 +381,25 @@ class Engine:
                 self._evict_lane(lane)
 
     # ---------------------------------------------------------------- step
+    def _call_forward(self, toks, block_tables, seq_lens):
+        """One forward call; its pools replace the pool's. Returns the
+        call's outputs: logits first, then the pools, then (a dropless
+        expert layer's forward) the claims on held experts."""
+        out = self._forward(self.params, toks, self.pool.k_pages,
+                            self.pool.v_pages, block_tables, seq_lens)
+        self.pool.k_pages, self.pool.v_pages = out[1], out[2]
+        return out
+
+    def _emit_routes(self, phase: str, out) -> None:
+        """With a flight recorder attached, the ``expert_route`` event of a
+        call that counted its claims: arg (claims on held experts, claims
+        dropped), the latter always 0 (the layer is dropless). Read after
+        the call's own host read, so it adds no sync; untraced runs read
+        nothing."""
+        if self._obs is not None and len(out) > 3:
+            self._obs.emit(EXPERT_ROUTE, phase, self.step_count,
+                           arg=(int(out[3]), 0))
+
     def step(self) -> List[Request]:
         """One engine iteration: tick window clock, reclaim, admit, decode.
         With a recorder attached the step and each of its phases are
@@ -402,16 +420,16 @@ class Engine:
             return []
         with self._span("engine.decode", attn=self._decode_attn):
             # Decode all lanes in one call on the device-resident tables.
-            logits, self.pool.k_pages, self.pool.v_pages = self._forward(
-                self.params, self.last_tok[:, None], self.pool.k_pages,
-                self.pool.v_pages, self.block_tables, self.seq_lens)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out = self._call_forward(self.last_tok[:, None], self.block_tables,
+                                     self.seq_lens)
+            nxt = jnp.argmax(out[0], axis=-1).astype(jnp.int32)
             mask = jnp.asarray(active_np)
             self.seq_lens = self.seq_lens + mask.astype(jnp.int32)
             self.last_tok = jnp.where(mask, nxt, self.last_tok)
             # single host sync per step for completion bookkeeping
             nxt_np = np.asarray(nxt)
             sl_np = np.asarray(self.seq_lens)
+            self._emit_routes("decode", out)
         with self._span("engine.bookkeep"):
             return self._bookkeep(active_np, nxt_np, sl_np)
 

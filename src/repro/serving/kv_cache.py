@@ -37,6 +37,22 @@ from repro.core.domain import (
 )
 
 
+LANE = 128
+
+
+def page_widths(cfg: ModelConfig) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+    """(heads, width) of the k and the v stream of a page row. GQA: keys
+    and values of each KV head. Latent attention (MLA): one head, the k
+    stream holding the normed latent c and the v stream the rotary key,
+    padded to whole 128-lane tiles (zeros in the padding) so that both
+    streams are stored page by page and the decode kernel reads them in
+    place: 512 + 128 lanes for the 576 values DeepSeek-V2 keeps."""
+    if cfg.is_mla:
+        return (1, cfg.kv_lora_rank), (1, -(-cfg.qk_rope_head_dim // LANE) * LANE)
+    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    return (kv, hd), (kv, hd)
+
+
 class PagedKVPool:
     def __init__(self, cfg: ModelConfig, *, num_pages: int, page_size: int,
                  window: Optional[int] = None, dtype=None,
@@ -51,12 +67,12 @@ class PagedKVPool:
             steps_per_sec, resilience_s)
         r = cfg.pattern_repeats
         n_attn = sum(1 for k in cfg.block_pattern if k in ("dense", "moe", "hymba"))
-        self.layers = r * n_attn
+        self.layers = cfg.first_dense_layers + r * n_attn
         dt = dtype or jnp.dtype(cfg.dtype)
-        kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-        # [L, P, KV, page, hd] — stacked over attention layers
-        self.k_pages = jnp.zeros((self.layers, num_pages, kv, page_size, hd), dt)
-        self.v_pages = jnp.zeros((self.layers, num_pages, kv, page_size, hd), dt)
+        (kv, dk), (_, dv) = page_widths(cfg)
+        # [L, P, KV, page, d] — stacked over attention layers
+        self.k_pages = jnp.zeros((self.layers, num_pages, kv, page_size, dk), dt)
+        self.v_pages = jnp.zeros((self.layers, num_pages, kv, page_size, dv), dt)
         self.pool = sp.make(num_pages)
         # Optional tenant quota ledger (duck-typed: charge/credit, see
         # repro.sched.tenants.TenantQuotaLedger — kept out of the

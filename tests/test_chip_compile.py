@@ -155,3 +155,56 @@ def test_yi6b_decode_step_fits_one_chip(one_chip, monkeypatch):
           f"{mem.alias_size_in_bytes / GiB:.2f} GiB")
     assert total <= FITS_BYTES, total / GiB
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _mla_step(one_chip, batch, seq):
+    """DeepSeek-V2-Lite's served forward (one rank of 8-way expert
+    parallelism) at dsv2lite.decode's geometry: 6656 pages of 16, 2048
+    positions a lane; ``batch`` lanes of ``seq`` tokens."""
+    import re
+
+    from repro.serving.kv_cache import page_widths
+    cfg = get_config("deepseek-v2-lite")
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    params = jax.tree_util.tree_map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), params)
+    (kv, dk), (_, dv) = page_widths(cfg)
+    pools = [_spec(one_chip, (cfg.num_layers, 6656, kv, 16, d), jnp.bfloat16)
+             for d in (dk, dv)]
+    compiled = make_paged_forward(cfg).lower(
+        params, _spec(one_chip, (batch, seq)), *pools,
+        _spec(one_chip, (batch, 2048 // 16)), _spec(one_chip, (batch,))).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # every op whose result is a whole pool, or one layer's slice of it
+    pool_ops = re.findall(r"= bf16\[(?:27,)?6656,1,16,(?:512|128)\]\{[^}]*\} ([a-z-]+)",
+                          compiled.as_text())
+    return compiled, total, pool_ops
+
+
+def test_mla_decode_step_fits_one_chip_and_reads_the_pool_in_place(one_chip, monkeypatch):
+    """The served decode step of dsv2lite.decode (48 lanes): weights, the
+    latent pool and the call's outputs fit; attention is the latent
+    kernel, which reads both streams where they lie: no relayout and no
+    layer slice of the pool, only the one plain copy of each stream the
+    undonated forward makes and the lanes' slot updates."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # this host is a CPU
+    compiled, total, pool_ops = _mla_step(one_chip, 48, 1)
+    assert total <= FITS_BYTES, total / GiB
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_decode_attention" in text
+    assert sorted(set(pool_ops)) == ["copy", "dynamic-update-slice", "get-tuple-element",
+                                     "parameter"]
+    assert pool_ops.count("copy") == 2
+
+
+def test_mla_prefill_fits_one_chip(one_chip):
+    """The longest prompt of dsv2lite.decode (1536 tokens) prefilled beside
+    the pool: it fits, and its pages are written in place (one plain copy
+    of each undonated stream, no relayout)."""
+    _, total, pool_ops = _mla_step(one_chip, 1, 1536)
+    assert total <= FITS_BYTES, total / GiB
+    assert pool_ops.count("copy") == 2
