@@ -8,9 +8,10 @@ until idle -- on the published yi-6b shape (32 layers, d_model 4096, GQA
 ``PARAM_SEED``. Two serving runs share one set of params, one after the
 other, each fabric closed before the next opens: first the host policy
 drain, then the device admission ring (the Pallas kernel). Both must
-complete every request, with token-identical outputs. Then the paged
-prefill logits of one prompt are compared with the plain dense forward of
-``repro.models.model``, both in float32 at matmul precision "highest".
+complete every request, with token-identical outputs, and every forward
+call must donate its KV pools. Then the paged prefill logits of one prompt
+are compared with the plain dense forward of ``repro.models.model``, both
+in float32 at matmul precision "highest".
 
 Exits non-zero, printing no result, when JAX's default device is not a TPU,
 when the ``repro`` package is not in ``src/`` beside this script, or when
@@ -30,9 +31,10 @@ ARCH = "yi-6b"
 PARAM_SEED = 0
 PROMPT_SEED = 1
 # Serving geometry: params (11.29 GiB) + the KV pool (1.0 GiB) + the
-# second pool each forward call writes (the pool is not donated) + the
-# step's temporaries fit the chip's 16 GiB; tests/test_chip_compile.py
-# checks this geometry against the chip's compiler.
+# step's temporaries fit the chip's 16 GiB (each forward call donates the
+# pool and writes its tokens in place, so no second pool is allocated);
+# tests/test_chip_compile.py checks this geometry against the chip's
+# compiler.
 MAX_BATCH = 8
 PAGE_SIZE = 16
 NUM_PAGES = 1024
@@ -97,6 +99,10 @@ def _serve(label: str, device_admission: bool, prompts, params, dev):
             f"{steps} fabric steps, wall {time.perf_counter() - t0:.1f}s "
             f"incl. compile")
     (eng,) = fab.engines
+    _require(eng.pools_donated == eng.forward_calls > 0,
+             f"{label}: {eng.pools_donated} of {eng.forward_calls} forward "
+             f"calls donated their pools")
+    line += f"; pools donated {eng.pools_donated}/{eng.forward_calls} calls"
     ring = eng.admission_ring
     if device_admission:
         _require(ring is not None and ring.use_pallas,

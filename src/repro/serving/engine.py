@@ -121,6 +121,13 @@ class Engine:
         # sequence lengths — one jit, one compilation cache. Replicas pass a
         # shared callable so N engines share one compilation cache.
         self._forward = forward_fn or make_paged_forward(cfg)
+        # host counters of the pool hand-off: forward calls, and calls after
+        # which both pool arrays passed in read deleted (the forward donated
+        # them); the engine.decode/prefill spans' ``donated`` is the previous
+        # call's outcome (False before the first call)
+        self.forward_calls = 0
+        self.pools_donated = 0
+        self._donated = False
         # which attention the decode step's forward takes (the engine.decode
         # span's ``attn``): the paged kernel or the whole-table gather, MLA's
         # prefixed ``mla_``
@@ -316,7 +323,8 @@ class Engine:
                 pages = self._alloc_pages(max(1, need))
             self.active[lane] = req
             self._lane_env[lane] = (qc, env)
-            with self._span("engine.prefill", prompt_len=len(req.prompt)):
+            with self._span("engine.prefill", prompt_len=len(req.prompt),
+                            donated=self._donated):
                 self.block_tables = self.block_tables.at[
                     lane, :len(pages)].set(jnp.asarray(pages))
                 self.seq_lens = self.seq_lens.at[lane].set(0)
@@ -384,10 +392,18 @@ class Engine:
     def _call_forward(self, toks, block_tables, seq_lens):
         """One forward call; its pools replace the pool's. Returns the
         call's outputs: logits first, then the pools, then (a dropless
-        expert layer's forward) the claims on held experts."""
-        out = self._forward(self.params, toks, self.pool.k_pages,
-                            self.pool.v_pages, block_tables, seq_lens)
+        expert layer's forward) the claims on held experts.
+
+        The only holder of the pool arrays: the served forward donates
+        them, so nothing may read the arrays passed in after the call.
+        Whether it did is read on the host (``is_deleted``, no device
+        sync); a caller's forward that does not donate works unchanged."""
+        kp, vp = self.pool.k_pages, self.pool.v_pages
+        out = self._forward(self.params, toks, kp, vp, block_tables, seq_lens)
         self.pool.k_pages, self.pool.v_pages = out[1], out[2]
+        self._donated = kp.is_deleted() and vp.is_deleted()
+        self.forward_calls += 1
+        self.pools_donated += self._donated
         return out
 
     def _emit_routes(self, phase: str, out) -> None:
@@ -418,7 +434,8 @@ class Engine:
         active_np = np.array([r is not None for r in self.active])
         if not active_np.any():
             return []
-        with self._span("engine.decode", attn=self._decode_attn):
+        with self._span("engine.decode", attn=self._decode_attn,
+                        donated=self._donated):
             # Decode all lanes in one call on the device-resident tables.
             out = self._call_forward(self.last_tok[:, None], self.block_tables,
                                      self.seq_lens)

@@ -347,6 +347,11 @@ def make_paged_forward(cfg: ModelConfig):
     """The engine's compiled step: ``(params, tokens, k_pages, v_pages,
     block_tables, seq_lens) -> (logits, k_pages', v_pages')``, and the
     claims on held experts too where the expert layer is dropless. Prefill
-    and decode are this one jit traced at different sequence lengths."""
+    and decode are this one jit traced at different sequence lengths.
+
+    The two pools are donated: the output pools alias the input buffers, so
+    the call writes its tokens in place instead of copying the whole pool
+    first, and the arrays passed in are deleted by the call."""
     return jax.jit(lambda p, t, kp, vp, bt, sl:
-                   paged_forward(p, t, cfg, kp, vp, bt, sl))
+                   paged_forward(p, t, cfg, kp, vp, bt, sl),
+                   donate_argnums=(2, 3))

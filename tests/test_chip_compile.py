@@ -128,8 +128,9 @@ def test_flash_attention_compiles_at_yi6b_widths(one_chip):
 
 def test_yi6b_decode_step_fits_one_chip(one_chip, monkeypatch):
     """The engine's decode step at full width, with the pool chip_smoke.py
-    serves from: params + pool + the call's outputs and temporaries fit, and
-    attention is the paged decode kernel, as served on a TPU."""
+    serves from: params + pool + the call's outputs and temporaries fit (the
+    output pools alias the donated input pools), and attention is the paged
+    decode kernel, as served on a TPU."""
     from repro.kernels import ops
     monkeypatch.setattr(ops, "on_tpu", lambda: True)  # this host is a CPU
     cfg = get_config("yi-6b")
@@ -157,54 +158,98 @@ def test_yi6b_decode_step_fits_one_chip(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _mla_step(one_chip, batch, seq):
-    """DeepSeek-V2-Lite's served forward (one rank of 8-way expert
-    parallelism) at dsv2lite.decode's geometry: 6656 pages of 16, 2048
-    positions a lane; ``batch`` lanes of ``seq`` tokens."""
+def _served_step(one_chip, arch, batch, seq, num_pages):
+    """The engine's served forward (``make_paged_forward``) for one chip at
+    a cell's geometry: ``num_pages`` pages of 16, 2048 positions a lane;
+    ``batch`` lanes of ``seq`` tokens. Returns the compiled step, the bytes
+    it needs (args + outputs + temporaries - aliased), the two pools' bytes,
+    and the kind of every op whose result is a whole pool."""
     import re
 
     from repro.serving.kv_cache import page_widths
-    cfg = get_config("deepseek-v2-lite")
+    cfg = get_config(arch)
     params = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     params = jax.tree_util.tree_map(
         lambda x: _spec(one_chip, x.shape, x.dtype), params)
     (kv, dk), (_, dv) = page_widths(cfg)
-    pools = [_spec(one_chip, (cfg.num_layers, 6656, kv, 16, d), jnp.bfloat16)
-             for d in (dk, dv)]
+    shapes = [(cfg.num_layers, num_pages, kv, 16, d) for d in (dk, dv)]
+    pools = [_spec(one_chip, s, jnp.dtype(cfg.dtype)) for s in shapes]
     compiled = make_paged_forward(cfg).lower(
         params, _spec(one_chip, (batch, seq)), *pools,
         _spec(one_chip, (batch, 2048 // 16)), _spec(one_chip, (batch,))).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    # every op whose result is a whole pool, or one layer's slice of it
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    whole = "|".join(re.escape(",".join(map(str, s))) for s in shapes)
+    pool_ops = re.findall(rf"= bf16\[(?:{whole})\]\{{[^}}]*\}} ([a-z-]+)",
+                          compiled.as_text())
+    return compiled, total, pool_bytes, pool_ops
+
+
+def _mla_step(one_chip, batch, seq):
+    """DeepSeek-V2-Lite's served forward (one rank of 8-way expert
+    parallelism) at dsv2lite.decode's geometry: 6656 pages of 16, 2048
+    positions a lane; ``batch`` lanes of ``seq`` tokens. Returns the
+    compiled step, its memory analysis, the bytes it needs and the kind of
+    every op whose result is a whole pool or one layer's slice of it."""
+    import re
+    compiled, total, _, _ = _served_step(one_chip, "deepseek-v2-lite", batch,
+                                         seq, 6656)
     pool_ops = re.findall(r"= bf16\[(?:27,)?6656,1,16,(?:512|128)\]\{[^}]*\} ([a-z-]+)",
                           compiled.as_text())
-    return compiled, total, pool_ops
+    return compiled, compiled.memory_analysis(), total, pool_ops
 
 
 def test_mla_decode_step_fits_one_chip_and_reads_the_pool_in_place(one_chip, monkeypatch):
     """The served decode step of dsv2lite.decode (48 lanes): weights, the
     latent pool and the call's outputs fit; attention is the latent
-    kernel, which reads both streams where they lie: no relayout and no
-    layer slice of the pool, only the one plain copy of each stream the
-    undonated forward makes and the lanes' slot updates."""
+    kernel, which reads both streams where they lie: no relayout, no layer
+    slice and no copy of the pool, only the lanes' slot updates written
+    into the donated streams, which the outputs alias."""
     from repro.kernels import ops
     monkeypatch.setattr(ops, "on_tpu", lambda: True)  # this host is a CPU
-    compiled, total, pool_ops = _mla_step(one_chip, 48, 1)
+    compiled, mem, total, pool_ops = _mla_step(one_chip, 48, 1)
     assert total <= FITS_BYTES, total / GiB
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "mla_decode_attention" in text
-    assert sorted(set(pool_ops)) == ["copy", "dynamic-update-slice", "get-tuple-element",
+    assert sorted(set(pool_ops)) == ["dynamic-update-slice", "get-tuple-element",
                                      "parameter"]
-    assert pool_ops.count("copy") == 2
+    assert mem.alias_size_in_bytes == 27 * 6656 * 16 * (512 + 128) * 2
 
 
 def test_mla_prefill_fits_one_chip(one_chip):
     """The longest prompt of dsv2lite.decode (1536 tokens) prefilled beside
-    the pool: it fits, and its pages are written in place (one plain copy
-    of each undonated stream, no relayout)."""
-    _, total, pool_ops = _mla_step(one_chip, 1, 1536)
+    the pool: it fits, and its pages are written in place into the donated
+    streams (no copy of either, no relayout)."""
+    _, _, total, pool_ops = _mla_step(one_chip, 1, 1536)
     assert total <= FITS_BYTES, total / GiB
-    assert pool_ops.count("copy") == 2
+    assert pool_ops.count("copy") == 0
+
+
+@pytest.mark.parametrize("arch,lanes,pages", [
+    ("yi-6b", 16, 1024),              # yi6b.decode
+    ("phi3-mini-3.8b", 8, 384),       # phi3.decode: a page-minor pool
+    ("deepseek-v2-lite", 48, 6656),   # dsv2lite.decode: the latent streams
+])
+def test_served_decode_step_donates_its_pools(one_chip, monkeypatch, arch, lanes,
+                                              pages):
+    """At each decode cell's geometry the served decode step fits, aliases
+    both pools to its outputs (they are donated) and makes no copy of a
+    whole pool: each step's tokens are written in place."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # this host is a CPU
+    compiled, total, pool_bytes, pool_ops = _served_step(one_chip, arch, lanes,
+                                                         1, pages)
+    assert total <= FITS_BYTES, total / GiB
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    assert pool_ops and "copy" not in pool_ops, pool_ops
+
+
+def test_dense_prefill_fits_one_chip(one_chip):
+    """A 256-token yi-6b prefill beside yi6b.decode's 1024-page pool fits
+    (dense prefill gathers the table and still copies the pool inside the
+    call; donation neither adds nor removes that)."""
+    _, total, _, _ = _served_step(one_chip, "yi-6b", 1, 256, 1024)
+    assert total <= FITS_BYTES, total / GiB

@@ -99,6 +99,9 @@ def test_span_names_args_and_clock_stat(traced):
     # this host has no TPU: every decode step gathered
     assert {s[3]["attn"] for s in spans if s[2] == "engine.decode"} \
         == {"gather"}
+    # the fabric served before the trace: every forward call donated its pools
+    assert {s[3]["donated"] for s in spans
+            if s[2] in ("engine.decode", "engine.prefill")} == {1}
     assert all(s[3]["generation"] in (0, 1, 2)
                for s in spans if s[2] == "host.gc")
 

@@ -422,6 +422,80 @@ def _serve_through_fabric(cfg, params, prompts):
     return [done[u].output for u in uids], attn
 
 
+def _undonated(cfg):
+    """A caller's forward: the served step jitted without donating its
+    pools."""
+    from repro.serving import paged_model
+    return jax.jit(lambda p, t, kp, vp, bt, sl:
+                   paged_model.paged_forward(p, t, cfg, kp, vp, bt, sl))
+
+
+@pytest.mark.parametrize("arch", ["yi_6b", "deepseek-v2-lite"],
+                         ids=["dense", "mla"])
+def test_donating_forward_serves_the_undonated_forwards_tokens(monkeypatch, arch):
+    """Through the fabric, the served forward (which donates the KV pools)
+    serves token for token what the same run serves on a forward that does
+    not donate; every call of the first donates both pools, none of the
+    second."""
+    from repro.fabric import Fabric, FabricConfig
+    from repro.serving import engine as E
+    cfg = get_config(arch, smoke=True)
+    params = init_params(cfg, KEY)
+    prompts = [[5, 17, 200, 3, 9, 9, 42, 1], [9, 9, 42], [100, 2, 7, 7, 1],
+               [11] * 15, [3, 1, 4, 1, 5, 9, 2, 6, 5]]
+
+    def serve():
+        config = FabricConfig(arch=cfg.name, smoke=True, max_batch=3,
+                              page_size=8, num_pages=48, kv_window=2,
+                              max_seq=64)
+        with Fabric.open(config, params=params, model_cfg=cfg) as fab:
+            uids = fab.submit_many(prompts, max_new_tokens=9)
+            done = fab.drain(max_steps=200)
+            (eng,) = fab.engines
+            counts = eng.forward_calls, eng.pools_donated
+        return [done[u].output for u in uids], counts
+
+    donated, (calls, n) = serve()
+    assert calls > len(prompts) and n == calls
+    monkeypatch.setattr(E, "make_paged_forward", _undonated)
+    kept, (calls, n) = serve()
+    assert calls > len(prompts) and n == 0
+    assert donated == kept
+
+
+def test_a_step_deletes_the_pools_it_hands_in(dense_model):
+    """After ``Engine.step`` the pool arrays handed into its forward calls
+    read deleted, the pool holds the calls' outputs, and the counters say
+    that every call donated."""
+    cfg, params = dense_model
+    eng = Engine(cfg, params, max_batch=2, page_size=8, num_pages=32,
+                 window=2, max_seq=64)
+    eng.submit([5, 17, 200, 3], max_new_tokens=5)
+    kp, vp = eng.pool.k_pages, eng.pool.v_pages
+    eng.step()  # a prefill and a decode call
+    assert kp.is_deleted() and vp.is_deleted()
+    assert not eng.pool.k_pages.is_deleted()
+    assert not eng.pool.v_pages.is_deleted()
+    assert eng.pools_donated == eng.forward_calls == 2
+
+
+def test_a_callers_undonating_forward_fn_works_unchanged(dense_model):
+    """A caller's forward that does not donate serves the reference's
+    tokens; its calls leave the pools they were given alive and count as
+    not donated."""
+    cfg, params = dense_model
+    eng = Engine(cfg, params, max_batch=2, page_size=8, num_pages=32,
+                 window=2, max_seq=64, forward_fn=_undonated(cfg))
+    prompt = [100, 2, 7, 7, 1]
+    u = eng.submit(prompt, max_new_tokens=5)
+    kp = eng.pool.k_pages
+    eng.step()
+    assert not kp.is_deleted()
+    done = eng.run_until_idle()
+    assert done[u].output == _ref_generate(cfg, params, prompt, 5)
+    assert eng.forward_calls == 5 and eng.pools_donated == 0
+
+
 @pytest.mark.parametrize("arch", [
     "phi3_mini",   # MHA, head_dim 16: each layer's pool slice
     "gqa_hd128",   # a group of 4 at head_dim 128: the pool read in place
