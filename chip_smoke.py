@@ -129,6 +129,7 @@ def _logits_gap(params, prompt):
 
     from repro.configs import get_config
     from repro.models import model as M
+    from repro.serving.kv_cache import page_widths
     from repro.serving.paged_model import paged_forward
 
     cfg = get_config(ARCH)
@@ -142,9 +143,9 @@ def _logits_gap(params, prompt):
         return {**p, "embed": p["embed"].astype(jnp.float32)}
 
     def paged(p, t):
-        shape = (cfg.num_layers, n_pages + 1, cfg.num_kv_heads, PAGE_SIZE,
-                 cfg.resolved_head_dim)
-        pool = jnp.zeros(shape, jnp.float32)
+        (kv, d), _ = page_widths(cfg)
+        pool = jnp.zeros((cfg.num_layers, n_pages + 1, kv, PAGE_SIZE, d),
+                         jnp.float32)
         table = jnp.arange(1, n_pages + 1, dtype=jnp.int32)[None]
         logits, _, _ = paged_forward(f32(p), t, cfg, pool, pool, table,
                                      jnp.zeros((1,), jnp.int32))

@@ -36,13 +36,13 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=0,
 
 
 def paged_attention(q, k_new, v_new, k_pages, v_pages, cached_lens, sched,
-                    layer=0):
+                    layer=0, *, sm_scale):
     """Decode attention of each lane's new token over its cached pages and
-    itself: q [B, H, hd]; k/v_new [B, KV, hd]; pages [L, P, KV, page, hd],
+    itself: q [B, H, d]; k/v_new [B, KV, d]; pages [L, P, KV, page, d],
     read at ``layer``; ``sched`` from ``paged_schedule``, one per call of
-    the model -> [B, H, hd]."""
+    the model; scores scaled by ``sm_scale`` -> [B, H, d]."""
     return _pa.paged_attention(q, k_new, v_new, k_pages, v_pages,
-                               cached_lens, sched, layer,
+                               cached_lens, sched, layer, sm_scale=sm_scale,
                                interpret=not on_tpu())
 
 

@@ -24,10 +24,12 @@ Numerics: bf16 (or float32) operands on the MXU with float32 accumulation,
 and an online softmax in float32. GQA grouping is r-major, as in the model
 (query head ``h`` reads KV head ``h % KV``).
 
-Layouts: q [B, H, hd]; k/v_new [B, KV, hd]; k/v pages [L, P, KV, page, hd],
+Layouts: q [B, H, d]; k/v_new [B, KV, d]; k/v pages [L, P, KV, page, d],
 read at ``layer``; block_tables [B, pages_per_seq] int32 (entries past a
 lane's live pages are never read); cached_lens [B] int32, the positions each
-lane holds before this token.
+lane holds before this token. ``d`` is the pool's row width; a model whose
+head is narrower pads q and the new token with zeros and passes its own
+``sm_scale``.
 
 Latent heads (MLA, ``latent_attention``): the same kernel over a pool of
 one head whose k stream holds the normed latent ``c`` and whose v stream
@@ -223,33 +225,35 @@ def _call(q, k_new, v_new, k_pages, v_pages, cached_lens, sched, layer, *,
       *([v_pages] * ppb))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def paged_attention(
-    q: jax.Array,             # [B, H, hd]
-    k_new: jax.Array,         # [B, KV, hd]
-    v_new: jax.Array,         # [B, KV, hd]
-    k_pages: jax.Array,       # [L, P, KV, page, hd]
+    q: jax.Array,             # [B, H, d]
+    k_new: jax.Array,         # [B, KV, d]
+    v_new: jax.Array,         # [B, KV, d]
+    k_pages: jax.Array,       # [L, P, KV, page, d]
     v_pages: jax.Array,
     cached_lens: jax.Array,   # [B] int32
     sched: Schedule,          # schedule(block_tables, cached_lens, page)
     layer: jax.Array | int = 0,
     *,
+    sm_scale: float,
     interpret: bool = False,
 ) -> jax.Array:
     """Each lane's new token (q, k_new, v_new) attends over the
     ``cached_lens[b]`` positions its block table holds in layer ``layer`` of
-    the pool, and over itself. Returns [B, H, hd]."""
-    B, H, hd = q.shape
+    the pool, and over itself, its scores scaled by ``sm_scale``. Returns
+    [B, H, d]."""
+    B, H, d = q.shape
     KV = k_pages.shape[2]
     rep = H // KV
     # r-major groups: head h = r * KV + g reads KV head g
-    qg = q.reshape(B, rep, KV, hd).transpose(0, 2, 1, 3)       # [B, KV, rep, hd]
-    kn = k_new.astype(k_pages.dtype)[:, :, None]                # [B, KV, 1, hd]
+    qg = q.reshape(B, rep, KV, d).transpose(0, 2, 1, 3)        # [B, KV, rep, d]
+    kn = k_new.astype(k_pages.dtype)[:, :, None]                # [B, KV, 1, d]
     vn = v_new.astype(v_pages.dtype)[:, :, None]
     out = _call(qg, kn, vn, k_pages, v_pages, cached_lens, sched, layer,
-                latent=False, sm_scale=1.0 / (hd ** 0.5),
+                latent=False, sm_scale=sm_scale,
                 name="paged_decode_attention", interpret=interpret)
-    return out.transpose(0, 2, 1, 3).reshape(B, H, hd)
+    return out.transpose(0, 2, 1, 3).reshape(B, H, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))

@@ -40,16 +40,23 @@ from repro.core.domain import (
 LANE = 128
 
 
+def _tiles(width: int) -> int:
+    return -(-width // LANE) * LANE
+
+
 def page_widths(cfg: ModelConfig) -> Tuple[Tuple[int, int], Tuple[int, int]]:
-    """(heads, width) of the k and the v stream of a page row. GQA: keys
-    and values of each KV head. Latent attention (MLA): one head, the k
-    stream holding the normed latent c and the v stream the rotary key,
-    padded to whole 128-lane tiles (zeros in the padding) so that both
-    streams are stored page by page and the decode kernel reads them in
-    place: 512 + 128 lanes for the 576 values DeepSeek-V2 keeps."""
+    """(heads, width) of the k and the v stream of a page row, padded to
+    whole 128-lane tiles (zeros in the padding). XLA stores a pool whose
+    rows fill whole tiles page by page, so every layer of it is read where
+    it lies; a row of any other width is stored with the page index minor
+    and relayouted on every read. GQA: keys and values of each KV head,
+    ``head_dim`` rounded up (phi3-mini's 96 -> 128). Latent attention
+    (MLA): one head, the k stream holding the normed latent c at
+    ``kv_lora_rank`` and the v stream the rotary key rounded up: 512 + 128
+    lanes for the 576 values DeepSeek-V2 keeps."""
     if cfg.is_mla:
-        return (1, cfg.kv_lora_rank), (1, -(-cfg.qk_rope_head_dim // LANE) * LANE)
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+        return (1, cfg.kv_lora_rank), (1, _tiles(cfg.qk_rope_head_dim))
+    kv, hd = cfg.num_kv_heads, _tiles(cfg.resolved_head_dim)
     return (kv, hd), (kv, hd)
 
 

@@ -1,33 +1,31 @@
-"""Paged decode/prefill paths: model forward where attention reads/writes
-CMP-managed KV pages instead of a dense per-request cache.
+"""Paged prefill and decode: the model forward with attention reading and
+writing CMP-managed KV pages instead of a dense per-request cache.
 
-Supports attention-bearing families (dense / moe / vlm / audio backbone).
-Pages allocated to a request are *sequential in position* (page j covers
-positions [j*page, (j+1)*page)), so the gathered page sequence is position-
-ordered and the attention mask is a simple length mask.
+Supports attention-bearing families (dense / moe, GQA or latent
+attention). Pages allocated to a request are *sequential in position*
+(page j covers positions [j*page, (j+1)*page)), so a lane's gathered pages
+are position-ordered and the attention mask is a length mask.
 
-Two paths, chosen by ``kernel_attention`` from the call's shape and the
-model alone. Decode (one token per lane) on a TPU reads each lane's live
-pages straight from the pool the call received, through the
-``repro.kernels.paged_attention`` Pallas kernel, and writes every layer's
-new token into the pool after the layer loop. Everything
-else (prefill, softcapped logits, hosts without a TPU) writes each layer's
-tokens into its page slice and gathers each lane's whole page table, masked
-to its length (``_gathered_attention``).
+One layout: ``kv_cache.page_widths`` sets the width of a pool row, whole
+128-lane tiles, so XLA stores each pool page by page and every layer of it
+is read where it lies. ``_proj`` pads each layer's new rows to that width.
 
-Latent attention (MLA) keeps one latent head per token and layer
-(``kv_cache.page_widths``: c in the k stream, the rotary key in the v
-stream) and always reads the pool as the call received it, writing every
-layer's new tokens after the layer loop: decode on a TPU through the
-kernel's latent mode (absorbed), everything else by gathering each lane's
-latent and expanding keys and values from it (unabsorbed). A model's
-leading dense layers run before the scan over its pattern, in pool layers
-0 .. first_dense_layers - 1.
+One layer loop (``_layers``) serves every call, prefill and decode: each
+layer reads the pool as the call received it, and after the loop every
+layer's new tokens are written, one slice update per lane (decode) or per
+page (prefill). A model's leading dense layers run before the scan over
+its pattern, in pool layers 0 .. first_dense_layers - 1.
+
+Two attention providers, chosen by ``kernel_attention`` from the call's
+shape and the model alone. ``_kernel_attention``: decode on a TPU, through
+the ``repro.kernels.paged_attention`` Pallas kernel, which reads only each
+lane's live pages (its latent mode for MLA, absorbed).
+``_gathered_attention``: everything else; each lane's whole table
+gathered, the call's tokens laid over it at the lane's length (MLA expands
+keys and values from the latent, unabsorbed).
 """
 
 from __future__ import annotations
-
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,42 +50,22 @@ def _proj_qkv(x, p, cfg: ModelConfig, positions):
     return q, k, v
 
 
+def _pad_to(x, width: int):
+    """``x`` with zeros after its last axis, up to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def _proj(x, p, cfg: ModelConfig, positions):
-    """(q, the k and v streams' new rows): GQA's q, k, v; or MLA's
-    (q_nope, q_rope), c [B,S,1,dc] and k_rope [B,S,1,dr] zero-padded to the
-    v stream's width."""
-    if not cfg.is_mla:
-        return _proj_qkv(x, p, cfg, positions)
-    q_nope, q_rope, c, k_rope = mla.project(x, p, cfg, positions)
-    pad = page_widths(cfg)[1][1] - cfg.qk_rope_head_dim
-    k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, pad)))
-    return (q_nope, q_rope), c[:, :, None], k_rope[:, :, None]
-
-
-def _scatter_pages(k_pages, v_pages, k_new, v_new, block_tables, positions):
-    """k_pages [P,KV,pg,hd]; k_new [B,S,KV,hd]; positions [B,S] absolute."""
-    pg = k_pages.shape[2]
-    page_rows = jnp.take_along_axis(block_tables, positions // pg, axis=1)  # [B,S]
-    slots = positions % pg
-    k_pages = k_pages.at[page_rows, :, slots].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_rows, :, slots].set(v_new.astype(v_pages.dtype))
-    return k_pages, v_pages
-
-
-def _gathered_attention(q, k_pages, v_pages, block_tables, positions, seq_lens,
-                        softcap: float = 0.0):
-    """Gather each request's pages and run masked attention.
-    q [B,S,H,hd]; returns [B,S,H,hd]."""
-    B = q.shape[0]
-    P, KV, pg, hd = k_pages.shape
-    pps = block_tables.shape[1]
-    kg = k_pages[block_tables]  # [B, pps, KV, pg, hd]
-    vg = v_pages[block_tables]
-    kg = jnp.moveaxis(kg, 2, 3).reshape(B, pps * pg, KV, hd)
-    vg = jnp.moveaxis(vg, 2, 3).reshape(B, pps * pg, KV, hd)
-    k_pos = jnp.arange(pps * pg, dtype=jnp.int32)[None, :].repeat(B, axis=0)
-    k_pos = jnp.where(k_pos < seq_lens[:, None], k_pos, -1)  # mask invalid
-    return L.cache_attention(q, kg, vg, positions, k_pos, softcap=softcap)
+    """(q, the k and v streams' new rows [B, S, heads, width] zero-padded
+    to the pool's row widths): GQA's q, k, v; or MLA's (q_nope, q_rope), c
+    and k_rope."""
+    if cfg.is_mla:
+        q_nope, q_rope, c, k_rope = mla.project(x, p, cfg, positions)
+        q, k, v = (q_nope, q_rope), c[:, :, None], k_rope[:, :, None]
+    else:
+        q, k, v = _proj_qkv(x, p, cfg, positions)
+    (_, dk), (_, dv) = page_widths(cfg)
+    return q, _pad_to(k, dk), _pad_to(v, dv)
 
 
 def kernel_attention(S: int, cfg: ModelConfig) -> bool:
@@ -106,8 +84,8 @@ def decode_path(cfg: ModelConfig) -> str:
 
 def _paged_block(x, p, cfg: ModelConfig, kind: str, positions, attend):
     """One block; ``attend(q, k_new, v_new, p_attn) -> (attn, out)`` reads
-    (and may write) the KV pages, and its ``out`` is handed back with ``x``
-    and the block's claims on held experts (None where it has none)."""
+    the KV pages, and its ``out`` is handed back with ``x`` and the block's
+    claims on held experts (None where it has none)."""
     h_in = L.norm(x, p["ln1"], cfg.norm)
     q, k_new, v_new = _proj(h_in, p["attn"], cfg, positions)
     attn, out = attend(q, k_new, v_new, p["attn"])
@@ -129,101 +107,84 @@ def _routes_sum(routed):
     return sum((n for n in routed if n is not None), jnp.zeros((), jnp.int32))
 
 
-def _gathered_layers(x, params, cfg: ModelConfig, k_pages, v_pages,
-                     block_tables, positions, seq_lens):
-    """Each layer writes its tokens into its page slice, then gathers every
-    lane's whole table; the scan stacks the written slices."""
-    def step(x, xs):
-        layer_p, kp, vp = xs
-        new_kp, new_vp, routed = [], [], []
-        for j, kind in enumerate(cfg.block_pattern):
-            def attend(q, k_new, v_new, pa, kp=kp[j], vp=vp[j]):
-                kp, vp = _scatter_pages(kp, vp, k_new, v_new, block_tables,
-                                        positions)
-                return _gathered_attention(q, kp, vp, block_tables, positions,
-                                           seq_lens, cfg.attn_softcap), (kp, vp)
-            x, (nk, nv), n = _paged_block(x, layer_p[str(j)], cfg, kind,
-                                          positions, attend)
-            new_kp.append(nk)
-            new_vp.append(nv)
-            routed.append(n)
-        ys = (jnp.stack(new_kp), jnp.stack(new_vp))
-        return x, ys + ((_routes_sum(routed),) if cfg.moe_dropless else ())
-
-    r, n_pat = cfg.pattern_repeats, len(cfg.block_pattern)
-    kp_s = k_pages.reshape((r, n_pat) + k_pages.shape[1:])
-    vp_s = v_pages.reshape((r, n_pat) + v_pages.shape[1:])
-    x, ys = jax.lax.scan(step, x, (params["blocks"], kp_s, vp_s))
-    n = jnp.sum(ys[2]) if cfg.moe_dropless else None
-    return x, ys[0].reshape(k_pages.shape), ys[1].reshape(v_pages.shape), n
-
-
-def _layer_pool(k_pages, v_pages, layer):
-    """(k, v, layer to read): the pool where it lies when its pages are
-    whole 128-lane tiles, else layer ``layer``'s slice. A pool whose rows
-    fill whole tiles is stored page by page, and the kernel reads it in
-    place. Otherwise XLA stores it with the page index minor (no padding;
-    phi3-mini's head_dim 96), so no page is contiguous: the kernel then
-    reads each layer's slice, which XLA relayouts layer by layer (the whole
-    pool at once does not fit beside phi3-mini's weights)."""
-    if k_pages.shape[-1] % 128 == 0 and v_pages.shape[-1] % 128 == 0:
-        return k_pages, v_pages, layer
-    return (jax.lax.dynamic_slice_in_dim(k_pages, layer, 1),
-            jax.lax.dynamic_slice_in_dim(v_pages, layer, 1), 0)
-
-
-def _kernel_attention(cfg, k_pages, v_pages, block_tables, seq_lens):
-    """Decode attention through the Pallas kernel, reading the pool as the
-    call received it (the lanes' earlier tokens) plus the step's own token:
-    ``attention(q, k_new, v_new, p_attn, layer) -> attn``."""
+def _kernel_attention(cfg: ModelConfig, k_pages, v_pages, block_tables,
+                      seq_lens):
+    """Decode attention through the Pallas kernel: each lane's one new
+    token over the live pages its table holds in layer ``layer`` of the
+    pool as the call received it, and over itself. Returns
+    ``attention(q, k_new, v_new, p_attn, layer) -> attn``: GQA, or latent
+    attention absorbed (MLA)."""
     sched = kops.paged_schedule(block_tables, seq_lens, k_pages.shape[3])
 
     def gqa(q, k_new, v_new, pa, layer):
-        kp, vp, at = _layer_pool(k_pages, v_pages, layer)
+        hd = cfg.resolved_head_dim
         with jax.named_scope("paged_decode_attention"):
             attn = kops.paged_attention(
-                q[:, 0], k_new[:, 0], v_new[:, 0], kp, vp, seq_lens, sched, at)
-        return attn[:, None]
+                _pad_to(q[:, 0], k_new.shape[-1]), k_new[:, 0], v_new[:, 0],
+                k_pages, v_pages, seq_lens, sched, layer,
+                sm_scale=1.0 / (hd ** 0.5))
+        return attn[:, None, :, :hd]
 
     def latent(q, c_new, r_new, pa, layer):
         q_nope, q_rope = q
         q_lat = mla.absorb(q_nope[:, 0], pa, cfg)                 # [B, H, dc]
-        pad = r_new.shape[-1] - q_rope.shape[-1]
-        q_r = jnp.pad(q_rope[:, 0], ((0, 0), (0, 0), (0, pad)))
-        kp, vp, at = _layer_pool(k_pages, v_pages, layer)
+        q_r = _pad_to(q_rope[:, 0], r_new.shape[-1])
         with jax.named_scope("mla_decode_attention"):
             o_lat = kops.latent_attention(
                 jnp.concatenate([q_lat, q_r.astype(q_lat.dtype)], -1),
-                c_new[:, 0, 0], r_new[:, 0, 0], kp, vp, seq_lens, sched, at,
-                sm_scale=mla.softmax_scale(cfg))
+                c_new[:, 0, 0], r_new[:, 0, 0], k_pages, v_pages, seq_lens,
+                sched, layer, sm_scale=mla.softmax_scale(cfg))
         return mla.expand(o_lat, pa, cfg)[:, None]
 
     return latent if cfg.is_mla else gqa
 
 
-def _latent_gathered_attention(cfg, k_pages, v_pages, block_tables, positions,
-                               seq_lens):
-    """Latent attention without the kernel (prefill; decode off a TPU):
-    each lane's cached latent gathered from the pool as the call received
-    it, masked to its ``seq_lens``, then the new tokens; keys and values
-    expanded from the latent (unabsorbed)."""
-    B = block_tables.shape[0]
-    pps, pg = block_tables.shape[1], k_pages.shape[3]
-    k_pos = jnp.arange(pps * pg, dtype=jnp.int32)[None, :].repeat(B, axis=0)
-    k_pos = jnp.concatenate(
-        [jnp.where(k_pos < seq_lens[:, None], k_pos, -1), positions], axis=1)
+def _gathered_attention(cfg: ModelConfig, k_pages, v_pages, block_tables,
+                        seq_lens):
+    """Attention without the kernel (prefill; decode off a TPU or with a
+    logit softcap): each lane's whole table gathered from layer ``layer``
+    of the pool as the call received it, cut to the model's widths, the
+    call's S new tokens laid over it at ``seq_lens[b]``, and masked to
+    ``seq_lens + S``. Returns ``attention(q, k_new, v_new, p_attn, layer)
+    -> attn``: GQA, or latent attention with keys and values expanded from
+    the latent (MLA, unabsorbed)."""
+    B, pps = block_tables.shape
+    T = pps * k_pages.shape[3]
+
+    def rows(pages, new, layer):
+        """[B, T, heads, d]: the lanes' rows, ``d`` the width of ``new``
+        [B, S, heads, d], with ``new`` laid over them, one slice update per
+        lane."""
+        d = new.shape[-1]
+        g = pages[layer, block_tables][..., :d]          # [B, pps, h, pg, d]
+        g = jnp.moveaxis(g, 2, 3).reshape(B, T, g.shape[2], d)
+        new = new.astype(g.dtype)
+        for b in range(B):
+            g = jax.lax.dynamic_update_slice(g, new[b:b + 1], (b, seq_lens[b], 0, 0))
+        return g
+
+    def positions(S):
+        """(the queries' positions [B, S], the rows' [B, T], -1 past each
+        lane's length)."""
+        k_pos = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
+        k_pos = jnp.where(k_pos < (seq_lens + S)[:, None], k_pos, -1)
+        return seq_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :], k_pos
+
+    def gqa(q, k_new, v_new, pa, layer):
+        hd = cfg.resolved_head_dim
+        k = rows(k_pages, k_new[..., :hd], layer)
+        v = rows(v_pages, v_new[..., :hd], layer)
+        return L.cache_attention(q, k, v, *positions(q.shape[1]),
+                                 softcap=cfg.attn_softcap)
 
     def latent(q, c_new, r_new, pa, layer):
         q_nope, q_rope = q
-        dr = q_rope.shape[-1]
-        c = k_pages[layer, block_tables].reshape(B, pps * pg, -1)
-        r = v_pages[layer, block_tables].reshape(B, pps * pg, -1)
-        c = jnp.concatenate([c, c_new[:, :, 0].astype(c.dtype)], axis=1)
-        r = jnp.concatenate([r, r_new[:, :, 0].astype(r.dtype)], axis=1)
-        return mla.attend(q_nope, q_rope, c, r[..., :dr], pa, cfg, positions,
-                          k_pos)
+        c = rows(k_pages, c_new, layer)[:, :, 0]
+        r = rows(v_pages, r_new[..., :q_rope.shape[-1]], layer)[:, :, 0]
+        return mla.attend(q_nope, q_rope, c, r, pa, cfg,
+                          *positions(q_rope.shape[1]))
 
-    return latent
+    return latent if cfg.is_mla else gqa
 
 
 def _write_slots(pages, new, block_tables, positions):
@@ -259,13 +220,14 @@ def _write_runs(pages, new, block_tables, positions):
     return pages
 
 
-def _read_then_write_layers(x, params, cfg: ModelConfig, k_pages, v_pages,
-                            block_tables, positions, attention):
-    """Every layer reads the pool as the call received it through
-    ``attention(q, k_new, v_new, p_attn, layer)``; after the layer loop
-    each layer's new tokens are written. (A slice of the scan's stacked
-    output would be copied whole for the kernel, every layer.) The leading
-    dense layers run first, in pool layers 0 .. first_dense_layers - 1."""
+def _layers(x, params, cfg: ModelConfig, k_pages, v_pages, block_tables,
+            positions, attention):
+    """The layer loop of every call: each layer reads the pool as the call
+    received it through ``attention(q, k_new, v_new, p_attn, layer)``;
+    after the loop every layer's new tokens are written. (A write inside
+    the scan would make the pool its stacked output, copied whole for each
+    layer's read.) The leading dense layers run first, in pool layers
+    0 .. first_dense_layers - 1."""
     n_pat, lead = len(cfg.block_pattern), cfg.first_dense_layers
     S = positions.shape[1]
 
@@ -323,21 +285,10 @@ def paged_forward(params, tokens, cfg: ModelConfig, k_pages, v_pages,
     attn_kinds = [k for k in cfg.block_pattern if k in ("dense", "moe")]
     assert len(attn_kinds) == len(cfg.block_pattern), (
         "paged serving supports attention-based families only")
-    if kernel_attention(S, cfg):
-        x, k_pages, v_pages, n = _read_then_write_layers(
-            x, params, cfg, k_pages, v_pages, block_tables, positions,
-            _kernel_attention(cfg, k_pages, v_pages, block_tables, seq_lens))
-    elif cfg.is_mla:
-        x, k_pages, v_pages, n = _read_then_write_layers(
-            x, params, cfg, k_pages, v_pages, block_tables, positions,
-            _latent_gathered_attention(cfg, k_pages, v_pages, block_tables,
-                                       positions, seq_lens))
-    else:
-        assert not cfg.first_dense_layers, (
-            "leading dense layers are served with latent attention only")
-        x, k_pages, v_pages, n = _gathered_layers(
-            x, params, cfg, k_pages, v_pages, block_tables, positions,
-            seq_lens + S)
+    provider = _kernel_attention if kernel_attention(S, cfg) else _gathered_attention
+    x, k_pages, v_pages, n = _layers(
+        x, params, cfg, k_pages, v_pages, block_tables, positions,
+        provider(cfg, k_pages, v_pages, block_tables, seq_lens))
     x = L.norm(x, params["final_norm"], cfg.norm)
     logits = M._logits(x[:, -1:], params, cfg)[:, 0]
     return (logits, k_pages, v_pages) + ((n,) if cfg.moe_dropless else ())

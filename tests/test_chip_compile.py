@@ -79,14 +79,15 @@ def test_claim_compiles(one_chip, n):
 
 def _compile_decode_kernel(one_chip, cfg, batch, pages_shape, pps, layer):
     """The decode kernel with its schedule, as one layer of the served step
-    calls it, compiled for one chip."""
-    hd, bf = cfg.resolved_head_dim, jnp.bfloat16
-    kv = (batch, cfg.num_kv_heads, hd)
+    calls it (q and the new token as wide as a pool row), compiled for one
+    chip."""
+    d, bf = pages_shape[-1], jnp.bfloat16
+    kv = (batch, cfg.num_kv_heads, d)
     fn = jax.jit(lambda q, kn, vn, kp, vp, bt, cl: paged_attention.paged_attention(
         q, kn, vn, kp, vp, cl, paged_attention.schedule(bt, cl, pages_shape[-2]),
-        layer))
+        layer, sm_scale=1.0 / (cfg.resolved_head_dim ** 0.5)))
     return fn.lower(
-        _spec(one_chip, (batch, cfg.num_heads, hd), bf), _spec(one_chip, kv, bf),
+        _spec(one_chip, (batch, cfg.num_heads, d), bf), _spec(one_chip, kv, bf),
         _spec(one_chip, kv, bf), _spec(one_chip, pages_shape, bf),
         _spec(one_chip, pages_shape, bf), _spec(one_chip, (batch, pps)),
         _spec(one_chip, (batch,))).compile()
@@ -105,12 +106,16 @@ def test_paged_attention_compiles_at_yi6b_widths(one_chip):
 
 
 def test_paged_attention_compiles_at_phi3_widths(one_chip):
-    """MHA 32/32 at head_dim 96 (not a multiple of the 128-lane tile), one
-    layer's slice of the pool as the served step passes it, at phi3.decode's
-    serving geometry: 8 lanes, 384 pages of 16, 2048 positions."""
+    """MHA 32/32 at head_dim 96, its pool rows padded to one 128-lane tile
+    (``page_widths``), reading layer 5 of the whole stacked pool in place
+    at phi3.decode's serving geometry: 8 lanes, 384 pages of 16, 2048
+    positions."""
+    from repro.serving.kv_cache import page_widths
     cfg = get_config("phi3-mini-3.8b")
-    pages = (1, 384, cfg.num_kv_heads, 16, cfg.resolved_head_dim)
-    compiled = _compile_decode_kernel(one_chip, cfg, 8, pages, 2048 // 16, 0)
+    (kv, d), _ = page_widths(cfg)
+    assert d == 128
+    pages = (cfg.num_layers, 384, kv, 16, d)
+    compiled = _compile_decode_kernel(one_chip, cfg, 8, pages, 2048 // 16, 5)
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -163,7 +168,9 @@ def _served_step(one_chip, arch, batch, seq, num_pages):
     a cell's geometry: ``num_pages`` pages of 16, 2048 positions a lane;
     ``batch`` lanes of ``seq`` tokens. Returns the compiled step, the bytes
     it needs (args + outputs + temporaries - aliased), the two pools' bytes,
-    and the kind of every op whose result is a whole pool."""
+    the kind of every op whose result is a whole pool, and of every op whose
+    result has the shape of one layer of a pool (with or without its unit
+    layer axis)."""
     import re
 
     from repro.serving.kv_cache import page_widths
@@ -183,9 +190,12 @@ def _served_step(one_chip, arch, batch, seq, num_pages):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
     whole = "|".join(re.escape(",".join(map(str, s))) for s in shapes)
-    pool_ops = re.findall(rf"= bf16\[(?:{whole})\]\{{[^}}]*\}} ([a-z-]+)",
-                          compiled.as_text())
-    return compiled, total, pool_bytes, pool_ops
+    layer = "|".join(re.escape(",".join(map(str, s[1:]))) for s in shapes)
+    text = compiled.as_text()
+    pool_ops = re.findall(rf"= bf16\[(?:{whole})\]\{{[^}}]*\}} ([a-z-]+)", text)
+    layer_ops = re.findall(rf"= bf16\[(?:1,)?(?:{layer})\]\{{[^}}]*\}} ([a-z-]+)",
+                           text)
+    return compiled, total, pool_bytes, pool_ops, layer_ops
 
 
 def _mla_step(one_chip, batch, seq):
@@ -195,8 +205,8 @@ def _mla_step(one_chip, batch, seq):
     compiled step, its memory analysis, the bytes it needs and the kind of
     every op whose result is a whole pool or one layer's slice of it."""
     import re
-    compiled, total, _, _ = _served_step(one_chip, "deepseek-v2-lite", batch,
-                                         seq, 6656)
+    compiled, total, _, _, _ = _served_step(one_chip, "deepseek-v2-lite", batch,
+                                            seq, 6656)
     pool_ops = re.findall(r"= bf16\[(?:27,)?6656,1,16,(?:512|128)\]\{[^}]*\} ([a-z-]+)",
                           compiled.as_text())
     return compiled, compiled.memory_analysis(), total, pool_ops
@@ -230,26 +240,34 @@ def test_mla_prefill_fits_one_chip(one_chip):
 
 @pytest.mark.parametrize("arch,lanes,pages", [
     ("yi-6b", 16, 1024),              # yi6b.decode
-    ("phi3-mini-3.8b", 8, 384),       # phi3.decode: a page-minor pool
+    ("phi3-mini-3.8b", 8, 384),       # phi3.decode: head_dim 96 in 128 lanes
     ("deepseek-v2-lite", 48, 6656),   # dsv2lite.decode: the latent streams
 ])
 def test_served_decode_step_donates_its_pools(one_chip, monkeypatch, arch, lanes,
                                               pages):
     """At each decode cell's geometry the served decode step fits, aliases
-    both pools to its outputs (they are donated) and makes no copy of a
-    whole pool: each step's tokens are written in place."""
+    both pools to its outputs (they are donated), makes no copy of a whole
+    pool and no op the shape of one layer of it: the kernel reads every
+    layer where it lies, and each step's tokens are written in place."""
     from repro.kernels import ops
     monkeypatch.setattr(ops, "on_tpu", lambda: True)  # this host is a CPU
-    compiled, total, pool_bytes, pool_ops = _served_step(one_chip, arch, lanes,
-                                                         1, pages)
+    compiled, total, pool_bytes, pool_ops, layer_ops = _served_step(
+        one_chip, arch, lanes, 1, pages)
     assert total <= FITS_BYTES, total / GiB
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
     assert pool_ops and "copy" not in pool_ops, pool_ops
+    assert not layer_ops, layer_ops
 
 
-def test_dense_prefill_fits_one_chip(one_chip):
-    """A 256-token yi-6b prefill beside yi6b.decode's 1024-page pool fits
-    (dense prefill gathers the table and still copies the pool inside the
-    call; donation neither adds nor removes that)."""
-    _, total, _, _ = _served_step(one_chip, "yi-6b", 1, 256, 1024)
+@pytest.mark.parametrize("arch,seq,pages", [
+    ("yi-6b", 256, 1024),             # yi6b.chat's median prompt
+    ("phi3-mini-3.8b", 512, 384),     # phi3.decode's longest prompt
+])
+def test_dense_prefill_fits_one_chip(one_chip, arch, seq, pages):
+    """A dense prefill beside its cell's pool fits, reads each layer of the
+    pool where it lies and writes its pages in place into the donated
+    pools: no copy of a whole pool and no op the shape of one layer."""
+    _, total, _, pool_ops, layer_ops = _served_step(one_chip, arch, 1, seq, pages)
     assert total <= FITS_BYTES, total / GiB
+    assert pool_ops and "copy" not in pool_ops, pool_ops
+    assert not layer_ops, layer_ops

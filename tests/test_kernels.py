@@ -88,15 +88,24 @@ def _decode_case(H, KV, hd, page, pps, dtype, seed=0):
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_sweep(H, KV, hd, page, pps, dtype):
+    """The kernel, the oracle over the pool with each new token written,
+    and the model step's gathered attention over the pool as received plus
+    the new token agree."""
+    import dataclasses
+
+    from repro.configs import get_config
     from repro.serving.paged_model import _gathered_attention
     q, (kn, vn), poisoned, written, bt, cl = _decode_case(H, KV, hd, page,
                                                           pps, dtype)
     sched = ops.paged_schedule(bt, cl, page)
-    out = ops.paged_attention(q, kn, vn, poisoned[:1], poisoned[1:], cl, sched)
+    out = ops.paged_attention(q, kn, vn, poisoned[:1], poisoned[1:], cl, sched,
+                              sm_scale=1.0 / (hd ** 0.5))
     out = np.asarray(out, np.float32)
     ref = ref_paged_attention(q, written[0], written[1], bt, cl + 1)
-    gathered = _gathered_attention(q[:, None], written[0], written[1], bt,
-                                   cl[:, None], cl + 1)[:, 0]
+    cfg = dataclasses.replace(get_config("phi3_mini", smoke=True),
+                              num_heads=H, num_kv_heads=KV, head_dim=hd)
+    attend = _gathered_attention(cfg, poisoned[:1], poisoned[1:], bt, cl)
+    gathered = attend(q[:, None], kn[:, None], vn[:, None], None, 0)[:, 0]
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
     live = slice(1, None)   # the empty lane's gathers read its stale pages
     for want in (ref, gathered):

@@ -497,8 +497,8 @@ def test_a_callers_undonating_forward_fn_works_unchanged(dense_model):
 
 
 @pytest.mark.parametrize("arch", [
-    "phi3_mini",   # MHA, head_dim 16: each layer's pool slice
-    "gqa_hd128",   # a group of 4 at head_dim 128: the pool read in place
+    "phi3_mini",   # MHA, head_dim 16: rows padded to one 128-lane tile
+    "gqa_hd128",   # a group of 4 at head_dim 128: rows one tile, unpadded
 ])
 def test_decode_kernel_serves_the_gather_paths_tokens(monkeypatch, arch):
     """The paged decode kernel (interpret mode here) serves token for token
